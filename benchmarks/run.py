@@ -69,6 +69,8 @@ def main() -> None:
     ap.add_argument("--obs-json", default="BENCH_obs.json",
                     help="observability overhead JSON output path")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # obs is full-grid-only by default: a 3 % differential budget cannot
     # be measured on smoke-sized steps (per-step noise is itself ±5 %),

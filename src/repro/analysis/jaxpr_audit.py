@@ -37,11 +37,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
-
-try:                                      # event-counting backend (private
-    from jax._src import monitoring      # but stable across 0.4.x)
-except ImportError:                      # pragma: no cover - future jax
-    monitoring = None
+from jax import monitoring
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _NARROW_DTYPES = ("int8", "uint8", "bfloat16")
@@ -336,24 +332,24 @@ def tp_seam_self_test(model_axis: str = "model") -> ContractResult:
 
 # ------------------------------------------------------------------ C204
 class CompileCounter:
-    """Counts XLA backend compiles via jax's monitoring events."""
+    """Counts XLA backend compiles via jax's monitoring events, and keeps
+    the name of each compiled program (``jit(<function>)``)."""
 
     def __init__(self) -> None:
         self.count = 0
+        self.names: List[str] = []
 
     def _listener(self, event: str, duration: float, **kw) -> None:
         if event == _COMPILE_EVENT:
             self.count += 1
+            self.names.append(str(kw.get("fun_name")))
 
     def __enter__(self) -> "CompileCounter":
-        if monitoring is not None:
-            monitoring.register_event_duration_secs_listener(self._listener)
+        monitoring.register_event_duration_secs_listener(self._listener)
         return self
 
     def __exit__(self, *exc) -> bool:
-        if monitoring is not None:
-            monitoring._unregister_event_duration_listener_by_callback(
-                self._listener)
+        monitoring.unregister_event_duration_listener(self._listener)
         return False
 
 

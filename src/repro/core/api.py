@@ -167,7 +167,8 @@ def compute_stats(grads: PyTree, f: int, *, needs_dists: bool = True,
     With ``mesh_ctx`` the statistics run mesh-native (DESIGN.md §10): the
     worker axis is sharded over ``mesh_ctx.worker_axes`` inside a
     ``shard_map`` and every device computes only its row block of the
-    (n, n) matrix — bitwise-identical to the replicated path.
+    (n, n) matrix — bitwise-identical to the replicated path under
+    ``use_pallas``; see :func:`sharded_raw_stats` for the XLA substrate.
     """
     enc = _as_encoded(grads)
     if enc is not None:
@@ -261,9 +262,8 @@ class MeshContext:
 
 
 def _shard_map(fn, ctx: MeshContext, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _worker_index(ctx: MeshContext) -> Array:
@@ -285,8 +285,10 @@ def _block_stats_contrib(x_loc: Array, x_full: Array
 
     ``x_loc`` is this device's worker rows, ``x_full`` the gathered stack.
     Each output element is the same full-d reduction the replicated formula
-    computes, so the block is bitwise-identical to the matching rows of
-    ``_leaf_stats_contrib(x_full)`` (tests/test_spmd.py).
+    computes.  On the CPU the block is bitwise-identical to the matching
+    rows of ``_leaf_stats_contrib(x_full)`` (tests/test_spmd.py); on a TPU
+    XLA tiles the (n/W, n) and (n, n) grams differently, so the two agree
+    to f32 rounding only.
     """
     xl = x_loc.astype(jnp.float32)
     xf = x_full.astype(jnp.float32)
@@ -313,8 +315,10 @@ def sharded_raw_stats(grads: PyTree, *, mesh_ctx: MeshContext,
     distance phase decomposes across the worker shards, the paper's §IV
     parallelisation claim — and the blocks are reassembled by the out-spec.
     Raw contract matches :func:`leaf_sqdist_contrib` (no clamp, diagonal
-    kept), and the float summation order matches the replicated path
-    exactly, so results are bitwise-identical (tests/test_spmd.py).
+    kept).  Under ``use_pallas`` the kernels fix the float summation order,
+    so results are bitwise-identical to the replicated path on any backend;
+    the XLA substrate is bitwise on the CPU (tests/test_spmd.py) and within
+    f32 rounding on a TPU (:func:`_block_stats_contrib`).
 
     n not divisible by the worker-shard count is zero-row padded; padded
     rows decode/contract to exact zeros and are sliced away.  Under
@@ -423,7 +427,8 @@ def sharded_raw_stats_model_axis(grads: PyTree, *, mesh_ctx: MeshContext,
     Float caveat: the model-axis ``psum`` is a different summation order
     than the replicated full-d contraction, so parity with the replicated
     path is bitwise at M = 1 (plain CI) and ~1e-6 at M > 1 — unlike the
-    worker-axis sharding, which is bitwise at any W.  Leaf columns pad to
+    worker-axis sharding, which is bitwise at any W on the CPU and, with
+    the kernels, on a TPU.  Leaf columns pad to
     a multiple of M with exact zeros.
     """
     leaves = jax.tree.leaves(grads)
@@ -647,6 +652,8 @@ def _bulyan_leaf(w_ext: Array, w_agr: Array, beta: int,
             return out.reshape(leaf.shape[1:]).astype(leaf.dtype)
         # measured-crossover fallback: past the cliff the whole Pallas
         # stack loses (two-step loses too) — take the XLA substrate
+        from repro.obs import profile as _prof
+        _prof.record_xla("fused_select", n=w_ext.shape[1], d=numel)
         use_pallas = False
 
     if use_pallas or coord_chunk:
@@ -753,6 +760,9 @@ def _sharded_apply_leaf(plan: "AggPlan", leaf: Array, ctx: MeshContext,
         from repro.kernels import dispatch as kdispatch
         take_fused = kdispatch.fused_wins(n_pad, d_pad // M)
         take_pallas = take_fused
+        if not take_fused:
+            from repro.obs import profile as _prof
+            _prof.record_xla("fused_select", n=n_pad, d=d_pad // M)
 
     def local(xl):                                     # (n_loc, d_loc)
         xfull = jax.lax.all_gather(xl, ctx.worker_axes, axis=0, tiled=True)

@@ -246,13 +246,22 @@ def bulyan_coordinate_phase(G_ext: Array, G_agr: Array, beta: int) -> Array:
     ``G_agr[:, j]`` closest to M[j].  Purely coordinate-local → shards freely
     over the model axis.  ``kernels/coord_select.py`` is the Pallas version.
     """
-    theta = G_agr.shape[0]
     med = _median_axis0(G_ext)
     dist = jax.lax.stop_gradient(jnp.abs(G_agr - med[None]))  # (theta, ...)
     order = jnp.argsort(dist, axis=0)                   # (theta, ...)
     ranks = jnp.argsort(order, axis=0)                  # rank of each entry
-    sel = ranks < beta
-    return jnp.sum(jnp.where(sel, G_agr, 0.0), axis=0) / float(beta)
+    return _masked_row_mean(ranks < beta, G_agr, beta)
+
+
+def _masked_row_mean(sel: Array, x: Array, beta: int) -> Array:
+    """Sum of the ``sel``-ed rows of ``x`` over β, added row 0, row 1, …
+    in that order.  A spelled-out order (not ``jnp.sum``): a backend may
+    reduce an axis in any association, and the Pallas kernels
+    (``kernels/coord_select.py``) add in this order too."""
+    acc = jnp.where(sel[0], x[0], 0.0)
+    for i in range(1, x.shape[0]):
+        acc = acc + jnp.where(sel[i], x[i], 0.0)
+    return acc / float(beta)
 
 
 def _bulyan_family(G: Array, f: int, *, multi: bool,

@@ -16,6 +16,7 @@ aggregates over.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Iterator, Tuple
 
 import jax
@@ -52,10 +53,13 @@ def make_lm_batch(key: Array, vocab: int, batch: int, seq: int,
 
 def lm_batches(vocab: int, batch: int, seq: int, *, seed: int = 0
                ) -> Iterator[Dict[str, Array]]:
+    # one compiled generator for the stream: called eagerly, the scan's
+    # fresh body closure would compile again for every batch
+    make = jax.jit(functools.partial(make_lm_batch, vocab=vocab, batch=batch,
+                                     seq=seq, seed=seed + 77))
     step = 0
     while True:
-        key = jax.random.fold_in(jax.random.key(seed), step)
-        yield make_lm_batch(key, vocab, batch, seq, seed=seed + 77)
+        yield make(jax.random.fold_in(jax.random.key(seed), step))
         step += 1
 
 

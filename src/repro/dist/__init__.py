@@ -12,7 +12,9 @@ from repro.dist.trainer import (  # noqa: F401
     as_trainer_state,
     init_train_state,
     inject_byzantine,
+    jit_train_step,
     make_train_step,
+    replicate_on_mesh,
     split_workers,
 )
 from repro.dist import sharding  # noqa: F401

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -203,6 +203,25 @@ def _derive_mesh_ctx(shard_map_mesh, shard_map_axes, spmd
     return api.MeshContext.for_mesh(
         shard_map_mesh,
         worker_axes=tuple(shard_map_axes) if shard_map_axes else None)
+
+
+def replicate_on_mesh(tree: PyTree, mesh) -> PyTree:
+    """``tree`` placed replicated over every device of ``mesh``: where
+    ``launch/train.py --mesh`` keeps params and state."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
+
+
+def jit_train_step(step: Callable, mesh=None) -> Callable:
+    """``jax.jit`` of a train step.  With ``mesh`` (a ``shard_map_mesh=``
+    step whose params and state live replicated over it) every output is
+    pinned to that placement.  Left to the compiler, the step hands some
+    params and state back sharded like the apply's d-shards, and the next
+    call, seeing a new input placement, compiles again."""
+    if mesh is None:
+        return jax.jit(step)
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.jit(step, out_shardings=NamedSharding(mesh, PartitionSpec()))
 
 
 def init_train_state(opt: Optimizer, params: PyTree,

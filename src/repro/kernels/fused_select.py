@@ -40,10 +40,12 @@ budget.
 
 Numerics match ``core.gar.bulyan_coordinate_phase`` composed with the
 weight einsums bit-for-bit in interpret mode (tested in
-tests/test_substrates.py): the θ-axis median uses the same sorted values,
-ties in the β-selection break by row index, and the masked mean uses the
-same ``where``-sum.  The worker axis is zero-padded to a sublane multiple
-of 8 (exact: padded weight columns are zero).
+tests/test_substrates.py): the θ-axis median picks by rank count the
+values the reference's stable sort picks, ties in the β-selection break by
+row index, and the masked mean adds the rows in the reference's order
+(``kernels/coord_select.coordinate_phase``).  The worker axis is
+zero-padded to a sublane multiple of 8 (exact: padded weight columns are
+zero).
 """
 from __future__ import annotations
 
@@ -53,14 +55,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.coord_select import coordinate_phase
+
 Array = jax.Array
 
 
 def _select_tile(x, we, wa, *, beta: int):
     """The per-window pipeline: (n_pad, dt) fp32 tile + resident weights
     -> (dt,) aggregate.  Column-independent — see module header."""
-    theta = we.shape[0]
-
     # extraction einsums — MXU, contraction over the worker axis.  HIGHEST:
     # ext feeds the median/selection, so it must not lose bits to bf16-pass
     # matmuls on TPU (same rationale as core.api.leaf_sqdist_contrib).
@@ -73,23 +75,8 @@ def _select_tile(x, we, wa, *, beta: int):
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (theta, dt)
 
-    # coordinate phase — VPU, same math as coord_select.py's kernel
-    srt = jnp.sort(ext, axis=0)
-    if theta % 2:
-        med = srt[theta // 2]
-    else:
-        med = 0.5 * (srt[theta // 2 - 1] + srt[theta // 2])   # (dt,)
-
-    dist = jnp.abs(agr - med[None, :])               # (theta, dt)
-    # rank by counting: rank[i] = #{k: dist[k] < dist[i]} + #{k<i: ==}
-    lt = (dist[None, :, :] < dist[:, None, :]).astype(jnp.int32)
-    eq = (dist[None, :, :] == dist[:, None, :]).astype(jnp.int32)
-    row = jax.lax.broadcasted_iota(jnp.int32, (theta, theta, 1), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (theta, theta, 1), 1)
-    eq_lower = eq * (col < row).astype(jnp.int32)    # ties -> smaller index first
-    rank = jnp.sum(lt + eq_lower, axis=1)            # (theta, dt)
-    sel = rank < beta
-    return jnp.sum(jnp.where(sel, agr, 0.0), axis=0) / float(beta)
+    # coordinate phase — VPU, the math of coord_select.py's kernel
+    return coordinate_phase(ext, agr, beta)
 
 
 def _kernel(x_ref, we_ref, wa_ref, o_ref, *, beta: int, d_tile: int,

@@ -99,7 +99,7 @@ def autotune_d_tile(rows: int, d: int, *, scratch_rows: int = 0,
 def _select_scratch_rows(theta: int) -> int:
     """Tile-width-scaling intermediates of the selection kernels: the three
     (θ, θ) int32 rank-counting broadcasts (lt/eq/rank) plus a few fp32
-    (θ,)-row temporaries (ext/agr/srt/dist)."""
+    (θ,)-row temporaries (ext/agr/rank/dist)."""
     return 3 * theta * theta + 4 * theta
 
 
@@ -198,7 +198,14 @@ def stats_macro_tile(n_rows: int, d: int, d_tile: int, *,
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU, interpreted on the CPU (the tests); any other
+    backend is an error, never a silent interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run on 'tpu' (compiled) or 'cpu' "
+            f"(interpreted), not on {backend!r}")
+    return backend == "cpu"
 
 
 def _resolve(interpret: Optional[bool]) -> bool:

@@ -50,8 +50,9 @@ def run_contracts() -> Dict:
 
     from repro.analysis import jaxpr_audit as JA
     from repro.core import api
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = api.MeshContext.for_mesh(mesh)
     key = jax.random.key(0)
     grads = {"w": jax.random.normal(key, (11, 8, 32)),

@@ -8,14 +8,22 @@ see the single real CPU device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the aggregation path is
+    written for compiler-propagated (GSPMD) sharding, and JAX's default
+    ``Explicit`` axes reject its replicated (n, n) statistics."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16×16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
@@ -32,7 +40,7 @@ def make_host_mesh() -> Mesh:
     data = 1
     while n % (data * 2) == 0 and data * 2 <= n // (data * 2):
         data *= 2
-    return jax.make_mesh((data, n // data), ("data", "model"))
+    return make_mesh((data, n // data), ("data", "model"))
 
 
 def data_parallel_size(mesh: Mesh) -> int:

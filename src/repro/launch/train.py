@@ -17,14 +17,33 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.jaxpr_audit import CompileCounter
 from repro.checkpoint import save
 from repro.configs import ARCH_NAMES, RobustConfig, get_config
 from repro.data import lm_batches
-from repro.dist import init_train_state, make_train_step, split_workers
+from repro.dist import (init_train_state, jit_train_step, make_train_step,
+                        replicate_on_mesh, split_workers)
 from repro.dist.streaming import make_streaming_train_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro import models as MD
 from repro import obs as OBS
 from repro.optim import make_optimizer, warmup_cosine
+
+
+def worker_batch(cfg, batch, key, step: int, n_workers: int):
+    """One step's global token batch -> the (n_workers, per_worker, ...)
+    stacks the trainer takes, with the stub encoder frames (enc-dec) or
+    image prefix (VLM) the arch needs, drawn from ``key`` and ``step``."""
+    b = batch["tokens"].shape[0]
+    if cfg.is_encdec:
+        batch["frames"] = jax.random.normal(
+            jax.random.fold_in(key, 10_000 + step),
+            (b, cfg.n_frames, cfg.d_model), dtype=jnp.bfloat16)
+    if cfg.n_patches:
+        batch["prefix_embeds"] = jax.random.normal(
+            jax.random.fold_in(key, 20_000 + step),
+            (b, cfg.n_patches, cfg.d_model), dtype=jnp.bfloat16)
+    return split_workers(batch, n_workers)
 
 
 def main(argv=None) -> int:
@@ -89,6 +108,7 @@ def main(argv=None) -> int:
         args.reduced = True
         args.steps = min(args.steps, 3)
         args.log_every = 1
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -169,39 +189,39 @@ def main(argv=None) -> int:
                                             codec=args.codec,
                                             shard_map_mesh=mesh, hier=hier,
                                             obs=obs)
-    step_fn = jax.jit(step_fn)
+    step_fn = jit_train_step(step_fn, mesh)
+    if mesh is not None:
+        # the mesh step returns params and state replicated over the mesh;
+        # start them there, so that every step sees one placement and the
+        # step compiles once
+        params, state = replicate_on_mesh((params, state), mesh)
     tracer = OBS.SpanTracer() if args.obs else None
 
     global_batch = args.workers * args.per_worker_batch
     data = lm_batches(cfg.vocab_size, global_batch, args.seq, seed=args.seed)
     t0 = time.time()
     loss = float("nan")
-    for i in range(args.steps):
-        batch = next(data)
-        if cfg.is_encdec:
-            b = batch["tokens"].shape[0]
-            batch["frames"] = jax.random.normal(
-                jax.random.fold_in(key, 10_000 + i),
-                (b, cfg.n_frames, cfg.d_model), dtype=jnp.bfloat16)
-        if cfg.n_patches:
-            b = batch["tokens"].shape[0]
-            batch["prefix_embeds"] = jax.random.normal(
-                jax.random.fold_in(key, 20_000 + i),
-                (b, cfg.n_patches, cfg.d_model), dtype=jnp.bfloat16)
-        wb = split_workers(batch, args.workers)
-        if tracer is not None:
-            with tracer.span("step", round=i):
+    with CompileCounter() as compiles:
+        for i in range(args.steps):
+            wb = worker_batch(cfg, next(data), key, i, args.workers)
+            if tracer is not None:
+                with tracer.span("step", round=i):
+                    params, state, metrics = step_fn(
+                        params, state, wb, jax.random.fold_in(key, i))
+                    jax.block_until_ready(metrics["loss"])
+            else:
                 params, state, metrics = step_fn(params, state, wb,
                                                  jax.random.fold_in(key, i))
-                jax.block_until_ready(metrics["loss"])
-        else:
-            params, state, metrics = step_fn(params, state, wb,
-                                             jax.random.fold_in(key, i))
-        if i % args.log_every == 0 or i == args.steps - 1:
-            loss = float(metrics["loss"])
-            print(f"[train] step {i:5d} loss {loss:.4f} "
-                  f"lr {float(metrics['lr']):.2e} "
-                  f"({(time.time()-t0)/(i+1):.2f}s/step)", flush=True)
+            if i == 0:
+                first_step_compiles = compiles.count
+            if i % args.log_every == 0 or i == args.steps - 1:
+                loss = float(metrics["loss"])
+                print(f"[train] step {i:5d} loss {loss:.4f} "
+                      f"lr {float(metrics['lr']):.2e} "
+                      f"({(time.time()-t0)/(i+1):.2f}s/step)", flush=True)
+    if args.steps > 1:
+        print(f"[train] compiles after step 0: "
+              f"{compiles.count - first_step_compiles}")
     if args.ckpt_dir:
         path = save(args.ckpt_dir, args.steps, {"params": params})
         print(f"[train] checkpoint -> {path}")

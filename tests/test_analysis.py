@@ -13,12 +13,12 @@ import os
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import jaxpr_audit as JA
 from repro.analysis import lint, vmem
 from repro.core import api
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURES = os.path.join(REPO, "tests", "fixtures_analysis")
@@ -30,7 +30,7 @@ KEY = jax.random.key(0)
 
 def _mesh11():
     """A 1×1 (data, model) mesh: tracing needs axis *names*, not devices."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # ================================================================= lint
@@ -83,8 +83,8 @@ def test_c201_trips_on_model_axis_gather():
     def body(x):
         return jax.lax.all_gather(x, ("data", "model"), axis=0, tiled=True)
 
-    fn = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(None),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=P(None), check_vma=False)
     closed = jax.make_jaxpr(fn)(jnp.zeros((8, 16)))
     violations, gathers = JA.gather_violations(
         closed, allowed=10 ** 9, model_axis="model")
@@ -97,8 +97,8 @@ def test_c201_trips_on_oversized_gather():
     def body(x):
         return jax.lax.all_gather(x, "data", axis=0, tiled=True)
 
-    fn = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P(None),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=P(None), check_vma=False)
     closed = jax.make_jaxpr(fn)(jnp.zeros((8, 16)))
     violations, _ = JA.gather_violations(
         closed, allowed=8 * 16 - 1, model_axis="model")

@@ -313,3 +313,17 @@ def test_ops_interpret_resolved_outside_jit(monkeypatch):
     ops.pairwise_sqdist(x, d_tile=256, interpret=False)
     ops.pairwise_sqdist(x, d_tile=384)                # default: CPU backend
     assert seen == [False, True]
+
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True)])
+def test_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops._interpret() is interpret
+
+
+def test_other_backends_never_fall_back_to_the_interpreter(monkeypatch):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        ops._interpret()

@@ -184,7 +184,10 @@ def test_snapshot_validates_and_catches_corruption():
 def test_campaign_summary_golden():
     """The telemetry→obs port must not move a single byte of the
     ``sim.campaign.v1`` summary (the digest now lives in
-    ``obs.export.phase_summary``; ``telemetry.summarize`` delegates)."""
+    ``obs.export.phase_summary``; ``telemetry.summarize`` delegates).
+    The golden holds the random streams of ``jax_threefry_partitionable``
+    on, JAX's default since 0.5: with it off, the summary is the one the
+    file held under older JAX, to float digits."""
     from repro.sim.engine import run_campaign
     from repro.sim.scenario import AttackPhase, AttackSchedule, Scenario
     sc = Scenario(name="obs-golden", arch=ARCH, n_workers=N, f=F,
@@ -195,3 +198,18 @@ def test_campaign_summary_golden():
     got = json.dumps(run_campaign(sc).summary, sort_keys=True)
     with open(GOLDEN) as fh:
         assert got == fh.read().strip()
+
+
+def test_kernel_profiler_counts_leaves_sent_to_xla(monkeypatch):
+    """A leaf the dispatch table sends to the XLA substrate is recorded as
+    ``xla:fused_select``, so a profile accounts for every apply leaf."""
+    from repro.core import api
+    from repro.kernels import dispatch
+    monkeypatch.setattr(dispatch, "fused_wins", lambda n, numel: numel < 300)
+    G = jax.random.normal(KEY, (11, 500))
+    tree = {"small": G[:, :200], "big": G[:, 200:]}
+    with OBS.KernelProfiler() as prof:
+        api.aggregate_tree(tree, 2, "multi_bulyan", use_pallas=True)
+    got = sorted((r.kernel, r.d) for r in prof.records)
+    assert got == [("fused_select", 200), ("pairwise_stats", 200),
+                   ("pairwise_stats", 300), ("xla:fused_select", 300)]

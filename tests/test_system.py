@@ -4,6 +4,10 @@ Train a small LM with byzantine workers present under a strong attack and
 assert the robust GAR defends while plain averaging fails — Definition 1
 made executable — plus attacks/sharding/dryrun plumbing sanity.
 """
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 import jax
@@ -70,7 +74,6 @@ def test_param_specs_cover_every_leaf():
 
 def test_sanitize_spec_drops_indivisible():
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}
@@ -92,3 +95,44 @@ def test_dryrun_collective_parser():
     assert out["all-gather"] == 16 * 384 * 4096 * 2 + (4 + 8) * 4
     assert out["all-reduce"] == 128 * 4
     assert out["total"] == out["all-gather"] + out["all-reduce"]
+
+
+def test_compile_cache_follows_the_environment(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache goes to <checkout>/.jax_cache.  ``config.update``
+    is intercepted so that no test turns the cache on."""
+    from repro.launch import compile_cache as CC
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert CC.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    assert CC.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
+
+
+def test_train_cli_mesh_step_compiles_once(tmp_path):
+    """``launch/train.py --mesh host`` on 4 virtual CPU devices (its own
+    process: this one sees one device): the step and the data stream
+    compile during step 0 and never again.  The mesh step returns params
+    and state on the placement they came in on; it once handed them back
+    sharded, and step 1 compiled again.  The persistent cache is off, so
+    a compile cannot hide as a cache hit."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_ENABLE_COMPILATION_CACHE="false")
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--smoke",
+         "--mesh", "host", "--workers", "11", "--f", "2",
+         "--gar", "multi_bulyan", "--attack", "sign_flip"],
+        env=env, cwd=root, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "shape={'data': 2, 'model': 2}" in out.stdout, out.stdout
+    assert "[train] compiles after step 0: 0" in out.stdout, out.stdout
