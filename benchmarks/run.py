@@ -20,10 +20,6 @@ per section).  Sections:
                 (repro.serve): QPS × staleness bound × f with the stale
                 accounting replayed through the real gradient buffer;
                 persists BENCH_serving.json
-* obs         — observability overhead: instrumented vs uninstrumented
-                step (stacked/streaming/async), must stay < 3 %;
-                persists BENCH_obs.json (full grid only — smoke-sized
-                steps are too noisy for a 3 % differential budget)
 * roofline    — §Roofline terms from the dry-run artifacts (if present)
 
 Env: BENCH_SECTIONS=agg_time,accuracy,... to select a subset (unknown
@@ -43,7 +39,7 @@ import time
 from typing import List
 
 KNOWN_SECTIONS = ("agg_time", "accuracy", "resilience", "bandwidth",
-                  "hier", "serving", "obs", "roofline")
+                  "hier", "serving", "roofline")
 
 
 def main() -> None:
@@ -66,18 +62,13 @@ def main() -> None:
                     help="hierarchical scaling JSON output path")
     ap.add_argument("--serving-json", default="BENCH_serving.json",
                     help="closed-loop serving JSON output path")
-    ap.add_argument("--obs-json", default="BENCH_obs.json",
-                    help="observability overhead JSON output path")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    # obs is full-grid-only by default: a 3 % differential budget cannot
-    # be measured on smoke-sized steps (per-step noise is itself ±5 %),
-    # so CI gates the committed full-run BENCH_obs.json instead
     default_sections = "agg_time,accuracy,resilience,bandwidth,hier,serving" \
         if args.smoke else \
-        "agg_time,accuracy,resilience,bandwidth,hier,serving,obs,roofline"
+        "agg_time,accuracy,resilience,bandwidth,hier,serving,roofline"
     sections = os.environ.get("BENCH_SECTIONS", default_sections).split(",")
     unknown = [s for s in sections if s not in KNOWN_SECTIONS]
     if unknown:
@@ -113,10 +104,6 @@ def main() -> None:
         from benchmarks import serving
         serving.run(rows, smoke=args.smoke, json_path=args.serving_json)
         print(f"# serving done ({time.time()-t0:.0f}s)", file=sys.stderr)
-    if "obs" in sections:
-        from benchmarks import obs_overhead
-        obs_overhead.run(rows, smoke=args.smoke, json_path=args.obs_json)
-        print(f"# obs done ({time.time()-t0:.0f}s)", file=sys.stderr)
     if "roofline" in sections:
         from benchmarks import roofline
         derived = roofline.run(rows)

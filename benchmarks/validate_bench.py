@@ -31,10 +31,6 @@ the payload's ``schema`` field:
   non-decreasing order, and async QPS *strictly above* sync on every
   shared (τ ≥ 1, f > 0) cell — the bounded-staleness buffer must
   actually buy throughput where the byzantine contract is live;
-* obs (``bench.obs.v1``) — observability overhead cells from
-  ``benchmarks/obs_overhead.py``: every instrumented step type
-  (stacked/streaming/async) within the < 3 % per-step overhead budget
-  of its uninstrumented baseline;
 * analysis (``analysis.v1``) — the static-contract report from
   ``repro.launch.analyze``: zero committed lint violations, every
   sharding contract proven, two-level kernel estimates present at the
@@ -96,10 +92,6 @@ SERVING_FIELDS = ("qps", "round_us", "round_us_p50", "round_us_p95",
                   "reused_rounds", "f_defended_mean", "admitted_frac")
 SERVING_ROWS = ("multi_bulyan[sync]", "multi_bulyan[async]")
 _SERVING_KEY_RE = re.compile(r"^tau=(\d+),f=(\d+)$")
-OBS_SCHEMA = "bench.obs.v1"
-OBS_FIELDS = ("us_base", "us_obs", "overhead_frac")
-OBS_STEPS = ("stacked", "streaming", "async")
-OBS_MAX_OVERHEAD = 0.03
 
 
 def _fail(msg: str) -> "list[str]":
@@ -122,8 +114,8 @@ def _check_agg_time(path: str, results: dict) -> "list[str]":
                                 f"a positive finite number, got {us!r}")
     # the grid-coverage and residency gates apply to full-grid payloads
     # only: a CI smoke run rewrites this file with a single shallow cell
-    # (benchmarks/agg_time.py SMOKE_*), where a depth gate is vacuous —
-    # same split as BENCH_obs.json.  Any fused cell at d >=
+    # (benchmarks/agg_time.py SMOKE_*), where a depth gate is vacuous.
+    # Any fused cell at d >=
     # MONOTONE_MIN_D marks the payload full-grid.
     fused_cells = _cells_by_n(results.get("multi_bulyan[fused]", {}))
     full_grid = any(d >= MONOTONE_MIN_D
@@ -432,39 +424,6 @@ def _check_serving(path: str, results: dict) -> "list[str]":
     return problems
 
 
-def _check_obs(path: str, results: dict) -> "list[str]":
-    """The observability overhead gate: < 3 % on every step type."""
-    problems = []
-    for step in OBS_STEPS:
-        if step not in results:
-            problems.append(f"missing required obs step row {step!r}")
-    for step, cell in results.items():
-        if not isinstance(cell, dict):
-            problems.append(f"{step}: cell must be an object")
-            continue
-        missing = [f for f in OBS_FIELDS if f not in cell]
-        if missing:
-            problems.append(f"{step}: missing {missing}")
-        for f in ("us_base", "us_obs"):
-            v = cell.get(f)
-            if not isinstance(v, (int, float)) or not math.isfinite(v) \
-                    or v <= 0:
-                problems.append(f"{step}: {f} must be a positive finite "
-                                f"number, got {v!r}")
-        frac = cell.get("overhead_frac")
-        if not isinstance(frac, (int, float)) or not math.isfinite(frac):
-            problems.append(f"{step}: overhead_frac must be finite, "
-                            f"got {frac!r}")
-        elif frac >= OBS_MAX_OVERHEAD:
-            problems.append(
-                f"{step}: obs overhead {frac * 100:.2f}% >= "
-                f"{OBS_MAX_OVERHEAD * 100:.0f}% budget "
-                f"(us_base={cell.get('us_base')!r}, "
-                f"us_obs={cell.get('us_obs')!r}) — the in-graph registry "
-                "must stay effectively free")
-    return problems
-
-
 def _check_analysis(path: str, results: dict) -> "list[str]":
     """The static-contract report: ships only when everything is proven."""
     problems = []
@@ -547,8 +506,6 @@ def check(path: str) -> "list[str]":
         problems += _check_hier(path, results)
     elif schema == SERVING_SCHEMA:
         problems += _check_serving(path, results)
-    elif schema == OBS_SCHEMA:
-        problems += _check_obs(path, results)
     elif schema == ANALYSIS_SCHEMA:
         problems += _check_analysis(path, results)
     elif schema == AGG_TIME_SCHEMA or schema is None:
@@ -558,7 +515,7 @@ def check(path: str) -> "list[str]":
     else:
         problems.append(
             f"{path}: unrecognised schema {schema!r}; known: "
-            f"{[AGG_TIME_SCHEMA, RESILIENCE_SCHEMA, COMM_SCHEMA, ACCURACY_SCHEMA, HIER_SCHEMA, SERVING_SCHEMA, OBS_SCHEMA, ANALYSIS_SCHEMA]}")
+            f"{[AGG_TIME_SCHEMA, RESILIENCE_SCHEMA, COMM_SCHEMA, ACCURACY_SCHEMA, HIER_SCHEMA, SERVING_SCHEMA, ANALYSIS_SCHEMA]}")
     return problems
 
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import gar as G
+from repro.obs.trace import scope
 
 Array = jax.Array
 PyTree = Any
@@ -169,44 +170,49 @@ def compute_stats(grads: PyTree, f: int, *, needs_dists: bool = True,
     ``shard_map`` and every device computes only its row block of the
     (n, n) matrix — bitwise-identical to the replicated path under
     ``use_pallas``; see :func:`sharded_raw_stats` for the XLA substrate.
-    """
-    enc = _as_encoded(grads)
-    if enc is not None:
-        def enc_stats():
-            if mesh_ctx is not None:
-                raw, sq = sharded_raw_stats(enc, mesh_ctx=mesh_ctx,
-                                            use_pallas=use_pallas)
-                return finalize_dists(raw), sq
-            from repro.comm import codecs as CC
-            return CC.encoded_pairwise_stats(enc, use_pallas=use_pallas)
 
+    Everything here traces under the ``robust.stats`` scope.
+    """
+    with scope("stats"):
+        enc = _as_encoded(grads)
+        if enc is not None:
+            def enc_stats():
+                if mesh_ctx is not None:
+                    raw, sq = sharded_raw_stats(enc, mesh_ctx=mesh_ctx,
+                                                use_pallas=use_pallas)
+                    return finalize_dists(raw), sq
+                from repro.comm import codecs as CC
+                return CC.encoded_pairwise_stats(enc, use_pallas=use_pallas)
+
+            norms = None
+            if needs_dists and dists is None:
+                dists, norms = enc_stats()
+            if needs_norms and norms is None:
+                norms = enc_stats()[1]
+            return AggStats(n=enc.n, f=f, dists=dists, sq_norms=norms)
+        leaves = jax.tree.leaves(grads)
+        if not leaves:
+            raise ValueError("empty gradient pytree")
+        n = leaves[0].shape[0]
+        for leaf in leaves:
+            if leaf.shape[0] != n:
+                raise ValueError(
+                    "all leaves must share the worker axis size")
         norms = None
         if needs_dists and dists is None:
-            dists, norms = enc_stats()
+            if mesh_ctx is not None:
+                raw, norms = sharded_raw_stats(grads, mesh_ctx=mesh_ctx,
+                                               use_pallas=use_pallas)
+                dists = finalize_dists(raw)
+            else:
+                dists, norms = tree_pairwise_stats(grads,
+                                                   use_pallas=use_pallas)
         if needs_norms and norms is None:
-            norms = enc_stats()[1]
-        return AggStats(n=enc.n, f=f, dists=dists, sq_norms=norms)
-    leaves = jax.tree.leaves(grads)
-    if not leaves:
-        raise ValueError("empty gradient pytree")
-    n = leaves[0].shape[0]
-    for leaf in leaves:
-        if leaf.shape[0] != n:
-            raise ValueError("all leaves must share the worker axis size")
-    norms = None
-    if needs_dists and dists is None:
-        if mesh_ctx is not None:
-            raw, norms = sharded_raw_stats(grads, mesh_ctx=mesh_ctx,
-                                           use_pallas=use_pallas)
-            dists = finalize_dists(raw)
-        else:
-            dists, norms = tree_pairwise_stats(grads, use_pallas=use_pallas)
-    if needs_norms and norms is None:
-        # norms alone are O(n·d) row sums — replicated compute is cheaper
-        # than the sharded distance phase even on a mesh, and the values
-        # are identical (same per-leaf accumulation order)
-        norms = tree_sq_norms(grads)
-    return AggStats(n=n, f=f, dists=dists, sq_norms=norms)
+            # norms alone are O(n·d) row sums — replicated compute is
+            # cheaper than the sharded distance phase even on a mesh, and
+            # the values are identical (same per-leaf accumulation order)
+            norms = tree_sq_norms(grads)
+        return AggStats(n=n, f=f, dists=dists, sq_norms=norms)
 
 
 # ==========================================================================
@@ -824,8 +830,18 @@ def _sharded_apply_encoded(plan: "AggPlan", enc, ctx: MeshContext,
 # ==========================================================================
 # the Aggregator protocol + registry
 # ==========================================================================
+def _scoped_plan(plan):
+    def scoped(self, stats: AggStats) -> AggPlan:
+        with scope("plan"):
+            return plan(self, stats)
+    return functools.wraps(plan)(scoped)
+
+
 class Aggregator:
     """Two-phase GAR: ``plan`` on the (n, n) statistics, ``apply`` on d.
+
+    A subclass's own ``plan`` traces under the ``robust.plan`` scope
+    wherever it is called from, and ``apply`` under ``robust.apply``.
 
     Capability flags (class attributes):
     * ``needs_dists``       — plan consumes the pairwise-distance matrix;
@@ -842,6 +858,11 @@ class Aggregator:
     @staticmethod
     def min_n(f: int) -> int:
         return 1
+
+    def __init_subclass__(cls, **kw):
+        super().__init_subclass__(**kw)
+        if "plan" in cls.__dict__:
+            cls.plan = _scoped_plan(cls.__dict__["plan"])
 
     # ------------------------------------------------------------- phases
     def validate(self, n: int, f: int) -> None:
@@ -875,35 +896,38 @@ class Aggregator:
         more than (n, d/M) of the stack (DESIGN.md §10); wire containers
         with a dequant-form codec shard the quantized payload and decode
         per shard instead of decoding replicated.
+
+        Everything here traces under the ``robust.apply`` scope.
         """
-        enc = _as_encoded(grads)
-        if enc is not None:
+        with scope("apply"):
+            enc = _as_encoded(grads)
+            if enc is not None:
+                if mesh_ctx is not None:
+                    return _sharded_apply_encoded(
+                        plan, enc, mesh_ctx, self._coordinate_leaf,
+                        use_pallas=use_pallas, fused=fused)
+                from repro.comm import codecs as CC
+                grads = CC.get_codec(enc.spec).decode(enc)
             if mesh_ctx is not None:
-                return _sharded_apply_encoded(
-                    plan, enc, mesh_ctx, self._coordinate_leaf,
+                fn = functools.partial(
+                    _sharded_apply_leaf, plan, ctx=mesh_ctx,
+                    coordinate_fn=self._coordinate_leaf,
                     use_pallas=use_pallas, fused=fused)
-            from repro.comm import codecs as CC
-            grads = CC.get_codec(enc.spec).decode(enc)
-        if mesh_ctx is not None:
-            fn = functools.partial(
-                _sharded_apply_leaf, plan, ctx=mesh_ctx,
-                coordinate_fn=self._coordinate_leaf,
-                use_pallas=use_pallas, fused=fused)
-            return jax.tree.map(lambda x: fn(x), grads)
-        if plan.kind == "mean":
-            return jax.tree.map(lambda x: jnp.mean(x, axis=0), grads)
-        if plan.kind == "weighted":
-            return jax.tree.map(
-                functools.partial(_weighted_mean_leaf, plan.weights), grads)
-        if plan.kind == "bulyan":
-            fn = functools.partial(_bulyan_leaf, plan.w_ext, plan.w_agr,
-                                   plan.beta, coord_chunk=coord_chunk,
-                                   use_pallas=use_pallas, fused=fused)
-            return jax.tree.map(fn, grads)
-        if plan.kind == "coordinate":
-            return jax.tree.map(
-                functools.partial(self._coordinate_leaf, plan), grads)
-        raise ValueError(f"unknown plan kind {plan.kind!r}")
+                return jax.tree.map(lambda x: fn(x), grads)
+            if plan.kind == "mean":
+                return jax.tree.map(lambda x: jnp.mean(x, axis=0), grads)
+            if plan.kind == "weighted":
+                return jax.tree.map(functools.partial(
+                    _weighted_mean_leaf, plan.weights), grads)
+            if plan.kind == "bulyan":
+                fn = functools.partial(_bulyan_leaf, plan.w_ext, plan.w_agr,
+                                       plan.beta, coord_chunk=coord_chunk,
+                                       use_pallas=use_pallas, fused=fused)
+                return jax.tree.map(fn, grads)
+            if plan.kind == "coordinate":
+                return jax.tree.map(
+                    functools.partial(self._coordinate_leaf, plan), grads)
+            raise ValueError(f"unknown plan kind {plan.kind!r}")
 
     def _coordinate_leaf(self, plan: AggPlan, leaf: Array) -> Array:
         raise NotImplementedError
@@ -1004,8 +1028,9 @@ def select_plan(pred: Array, on_true: AggPlan, on_false: AggPlan) -> AggPlan:
     match; they do whenever both came from the same backend).  This is how
     the async service degrades an inadmissible round to the previous
     round's plan without changing any traced shape."""
-    return jax.tree.map(lambda a, b: jnp.where(pred, a, b),
-                        on_true, on_false)
+    with scope("plan"):
+        return jax.tree.map(lambda a, b: jnp.where(pred, a, b),
+                            on_true, on_false)
 
 
 REGISTRY: Dict[str, Aggregator] = {}

@@ -22,6 +22,9 @@ final_norm / lm_head for the decoder-only stack).  Per-block gradients are
 taken wrt the block subtree with the rest of the parameters closed over, so
 each value equals the corresponding slice of the full gradient.
 
+Phases trace under the stacked trainer's ``robust.*`` scopes
+(``repro.obs.scope``); pass 1's distance accumulation is ``robust.stats``.
+
 With ``rcfg.use_pallas`` both trainers ride the fused kernel stack: block
 statistics come from the single-pass ``pairwise_stats`` kernel (one HBM
 read per leaf for distances + norms) and the bulyan apply runs entirely in
@@ -188,7 +191,8 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
             """Per-worker grads wrt block k of p (others closed over)."""
             if k is None:
                 vg = jax.value_and_grad(worker_loss)
-                out = jax.vmap(lambda wb: vg(p, wb))(batch)
+                with OBS.scope("workers"):
+                    out = jax.vmap(lambda wb: vg(p, wb))(batch)
                 return out if with_loss else out[1]
 
             def loss_of(bp, wb):
@@ -197,7 +201,8 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                 return worker_loss(q, wb)
 
             vg = jax.value_and_grad(loss_of)
-            out = jax.vmap(lambda wb: vg(p[k], wb))(batch)
+            with OBS.scope("workers"):
+                out = jax.vmap(lambda wb: vg(p[k], wb))(batch)
             return out if with_loss else out[1]
 
         blocks = [None] if block_keys is None else block_keys
@@ -216,15 +221,18 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
             (and wire-attack randomness) match the stacked trainer's
             bit for bit.
             """
-            if not wire:
-                g = inject_byzantine(g, f_eff, attack, key, leaf_offset=off)
-            if codec_obj is None:
-                return None, g
-            ekey = jax.random.fold_in(key, 2 ** 31 - 2)
-            enc, _ = codec_obj.encode(g, key=ekey, leaf_offset=off)
-            if wire:
-                enc = inject_wire(enc, f_eff, attack, key, leaf_offset=off)
-            return enc, codec_obj.decode(enc)
+            with OBS.scope("attack"):
+                if not wire:
+                    g = inject_byzantine(g, f_eff, attack, key,
+                                         leaf_offset=off)
+                if codec_obj is None:
+                    return None, g
+                ekey = jax.random.fold_in(key, 2 ** 31 - 2)
+                enc, _ = codec_obj.encode(g, key=ekey, leaf_offset=off)
+                if wire:
+                    enc = inject_wire(enc, f_eff, attack, key,
+                                      leaf_offset=off)
+                return enc, codec_obj.decode(enc)
 
         plan = None
         global_diag = None
@@ -242,23 +250,26 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                           for s, e in bounds]
                 for k in blocks:
                     enc, g = wire_block(block_grads(params, k), offsets[k])
-                    if enc is not None:
-                        from repro.comm import codecs as CC
-                        for gi, (s, e) in enumerate(bounds):
-                            totals[gi] = totals[gi] + api.raw_pairwise_stats(
-                                CC.slice_workers(enc, s, e),
-                                use_pallas=rcfg.use_pallas)[0]
-                    else:
-                        for leaf in jax.tree.leaves(g):
+                    with OBS.scope("stats"):
+                        if enc is not None:
+                            from repro.comm import codecs as CC
                             for gi, (s, e) in enumerate(bounds):
                                 totals[gi] = totals[gi] + \
                                     api.raw_pairwise_stats(
-                                        leaf[s:e],
+                                        CC.slice_workers(enc, s, e),
                                         use_pallas=rcfg.use_pallas)[0]
-                hier_inner_stats = tuple(
-                    api.AggStats(n=e - s, f=hier_budget.f_inner,
-                                 dists=api.finalize_dists(t))
-                    for (s, e), t in zip(bounds, totals))
+                        else:
+                            for leaf in jax.tree.leaves(g):
+                                for gi, (s, e) in enumerate(bounds):
+                                    totals[gi] = totals[gi] + \
+                                        api.raw_pairwise_stats(
+                                            leaf[s:e],
+                                            use_pallas=rcfg.use_pallas)[0]
+                with OBS.scope("stats"):
+                    hier_inner_stats = tuple(
+                        api.AggStats(n=e - s, f=hier_budget.f_inner,
+                                     dists=api.finalize_dists(t))
+                        for (s, e), t in zip(bounds, totals))
             else:
                 hier_inner_stats = tuple(
                     api.AggStats(n=e - s, f=hier_budget.f_inner)
@@ -282,25 +293,30 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
             total = jnp.zeros((rcfg.n_workers, rcfg.n_workers), jnp.float32)
             for k in blocks:
                 enc, g = wire_block(block_grads(params, k), offsets[k])
-                if enc is not None:
-                    total = total + api.raw_pairwise_stats(
-                        enc, use_pallas=rcfg.use_pallas, mesh_ctx=mesh_ctx)[0]
-                    continue
-                # leaf-by-leaf into the running total: one flat left-to-
-                # right float accumulation across ALL blocks' leaves, the
-                # exact summation order of the stacked single pass —
-                # grouping per block would reassociate the (n, n) sums by
-                # up to ~last-ulp·leaves, enough to flip near-tied scores
-                for leaf in jax.tree.leaves(g):
-                    total = total + api.raw_pairwise_stats(
-                        leaf, use_pallas=rcfg.use_pallas,
-                        mesh_ctx=mesh_ctx)[0]
-            stats = api.AggStats(n=rcfg.n_workers, f=rcfg.f,
-                                 dists=api.finalize_dists(total))
+                with OBS.scope("stats"):
+                    if enc is not None:
+                        total = total + api.raw_pairwise_stats(
+                            enc, use_pallas=rcfg.use_pallas,
+                            mesh_ctx=mesh_ctx)[0]
+                        continue
+                    # leaf-by-leaf into the running total: one flat left-to-
+                    # right float accumulation across ALL blocks' leaves,
+                    # the exact summation order of the stacked single pass
+                    # — grouping per block would reassociate the (n, n)
+                    # sums by up to ~last-ulp·leaves, enough to flip
+                    # near-tied scores
+                    for leaf in jax.tree.leaves(g):
+                        total = total + api.raw_pairwise_stats(
+                            leaf, use_pallas=rcfg.use_pallas,
+                            mesh_ctx=mesh_ctx)[0]
+            with OBS.scope("stats"):
+                stats = api.AggStats(n=rcfg.n_workers, f=rcfg.f,
+                                     dists=api.finalize_dists(total))
             aggregator.validate(stats.n, stats.f)
             plan = aggregator.plan(stats)
             if telemetry:
-                global_diag = plan.diagnostics(stats)
+                with OBS.scope("update"):
+                    global_diag = plan.diagnostics(stats)
             # The barrier is what makes this a *streaming* trainer once
             # compiled: pass-2 recomputes byte-identical per-block gradient
             # subgraphs, and without it XLA CSE would dedupe them against
@@ -354,10 +370,11 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                 agg_blocks[k] = agg_k
                 leader_total += hinfo_k["leader_wire_bytes"]
                 if telemetry:
-                    block_diags.append(
-                        hplan_k.diagnostics(hinfo_k["inner_stats"]))
-                    dev_sq, ref_sq = honest_dev_accumulate(
-                        dev_sq, ref_sq, agg_k, g, f_eff)
+                    with OBS.scope("update"):
+                        block_diags.append(
+                            hplan_k.diagnostics(hinfo_k["inner_stats"]))
+                        dev_sq, ref_sq = honest_dev_accumulate(
+                            dev_sq, ref_sq, agg_k, g, f_eff)
                 continue
             if hier is not None:
                 # scope == "global": apply the global inner plans per
@@ -375,9 +392,10 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                 if telemetry:
                     # honest means are d-sized — keep them for the
                     # deviation once the outer aggregate exists
-                    hm_blocks[k] = jax.tree.map(
-                        lambda x: jnp.mean(x[f_eff:].astype(jnp.float32),
-                                           axis=0), g)
+                    with OBS.scope("update"):
+                        hm_blocks[k] = jax.tree.map(
+                            lambda x: jnp.mean(
+                                x[f_eff:].astype(jnp.float32), axis=0), g)
                 continue
             block_plan = plan
             if block_plan is None or (telemetry and scope == "block"):
@@ -389,13 +407,15 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                     aggregator.validate(stats_k.n, stats_k.f)
                     block_plan = aggregator.plan(stats_k)
                 if telemetry:
-                    block_diags.append(block_plan.diagnostics(stats_k))
+                    with OBS.scope("update"):
+                        block_diags.append(block_plan.diagnostics(stats_k))
             agg_blocks[k] = aggregator.apply(
                 block_plan, g, coord_chunk=coord_chunk,
                 use_pallas=rcfg.use_pallas, mesh_ctx=mesh_ctx)
             if telemetry:
-                dev_sq, ref_sq = honest_dev_accumulate(
-                    dev_sq, ref_sq, agg_blocks[k], g, f_eff)
+                with OBS.scope("update"):
+                    dev_sq, ref_sq = honest_dev_accumulate(
+                        dev_sq, ref_sq, agg_blocks[k], g, f_eff)
 
         if hier is not None and scope == "global":
             # outer phase, once, over the stored (n_groups, ...) stack
@@ -408,9 +428,10 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                 if codec_obj is not None:
                     from repro.hier import LEADER_ENCODE_FOLD
                     k2 = jax.random.fold_in(key, LEADER_ENCODE_FOLD)
-                    enc2, _ = codec_obj.encode(inter, key=k2)
+                    with OBS.scope("attack"):
+                        enc2, _ = codec_obj.encode(inter, key=k2)
+                        inter = codec_obj.decode(enc2)
                     leader_total += enc2.wire_bytes
-                    inter = codec_obj.decode(enc2)
                 ost = api.compute_stats(
                     inter, hier_budget.f_outer,
                     needs_dists=outer_agg.needs_dists or telemetry,
@@ -421,70 +442,74 @@ def make_streaming_train_step(cfg: ArchConfig, rcfg: RobustConfig,
                                       coord_chunk=coord_chunk,
                                       use_pallas=rcfg.use_pallas)
             if telemetry:
-                from repro.hier import HierPlan
-                hplan = HierPlan(
-                    inner=hier_inner_plans, outer=outer_plan,
-                    n=rcfg.n_workers, f=rcfg.f, g=hier.g,
-                    bounds=hier_budget.bounds(),
-                    f_inner=hier_budget.f_inner,
-                    f_outer=hier_budget.f_outer, rule=hier.rule,
-                    outer_rule=hier.resolve_outer_rule(hier_budget))
-                global_diag = hplan.diagnostics(hier_inner_stats)
-                hm = hm_blocks[None] if block_keys is None else \
-                    {k: hm_blocks[k] for k in block_keys}
-                for a, m in zip(jax.tree.leaves(agg), jax.tree.leaves(hm)):
-                    dev_sq = dev_sq + jnp.sum(
-                        (a.astype(jnp.float32) - m) ** 2)
-                    ref_sq = ref_sq + jnp.sum(m ** 2)
+                with OBS.scope("update"):
+                    from repro.hier import HierPlan
+                    hplan = HierPlan(
+                        inner=hier_inner_plans, outer=outer_plan,
+                        n=rcfg.n_workers, f=rcfg.f, g=hier.g,
+                        bounds=hier_budget.bounds(),
+                        f_inner=hier_budget.f_inner,
+                        f_outer=hier_budget.f_outer, rule=hier.rule,
+                        outer_rule=hier.resolve_outer_rule(hier_budget))
+                    global_diag = hplan.diagnostics(hier_inner_stats)
+                    hm = hm_blocks[None] if block_keys is None else \
+                        {k: hm_blocks[k] for k in block_keys}
+                    for a, m in zip(jax.tree.leaves(agg), jax.tree.leaves(hm)):
+                        dev_sq = dev_sq + jnp.sum(
+                            (a.astype(jnp.float32) - m) ** 2)
+                        ref_sq = ref_sq + jnp.sum(m ** 2)
         elif block_keys is None:
             agg = agg_blocks[None]
         else:
             agg = {k: agg_blocks[k] for k in block_keys}
 
-        lr = lr_fn(opt_state.step)
-        new_params, new_opt = opt.update(agg, opt_state, params, lr)
-        gnorm = jnp.sqrt(sum(
-            jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree.leaves(agg)))
-        metrics = {
-            "loss": jnp.mean(losses),
-            "loss_per_worker": losses,
-            "lr": jnp.asarray(lr, jnp.float32),
-            "agg_grad_norm": gnorm,
-        }
-        if telemetry:
-            if global_diag is not None:
-                diag = dict(global_diag)
-            else:
-                # scope == "block": selection is per-block; report the mean
-                # over block plans (the per-block degradation is the point)
-                diag = {kk: jnp.mean(jnp.stack([d[kk] for d in block_diags]),
-                                     axis=0)
-                        for kk in block_diags[0]}
-            # captured mass over the rows the attack actually holds (f_eff)
-            diag["byz_mass"] = jnp.sum(diag["selection"][:f_eff])
-            diag["honest_dev"] = honest_dev_finalize(dev_sq, ref_sq)
-            if codec_obj is not None:
-                diag["wire_bytes_per_worker"] = jnp.asarray(
-                    wire_total / rcfg.n_workers, jnp.float32)
-            if hier is not None and codec_obj is not None:
-                diag["leader_wire_bytes"] = jnp.asarray(
-                    leader_total, jnp.float32)
-            metrics["telemetry"] = diag
-        if obs_live:
-            m = mstate["m"]
-            m = OBS.inc(m, "rounds")
-            m = OBS.set_gauge(m, "loss", metrics["loss"])
-            m = OBS.set_gauge(m, "agg_grad_norm", gnorm)
-            m = OBS.observe(m, "agg_grad_norm", gnorm)
+        with OBS.scope("update"):
+            lr = lr_fn(opt_state.step)
+            new_params, new_opt = opt.update(agg, opt_state, params, lr)
+            gnorm = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                                 for g in jax.tree.leaves(agg)))
+            metrics = {
+                "loss": jnp.mean(losses),
+                "loss_per_worker": losses,
+                "lr": jnp.asarray(lr, jnp.float32),
+                "agg_grad_norm": gnorm,
+            }
             if telemetry:
-                m = OBS.set_gauge(m, "byz_mass", diag["byz_mass"])
-                m = OBS.set_gauge(m, "suspicion", OBS.update_suspicion(
-                    m.gauges["suspicion"], diag["selection"],
-                    obs.suspicion_ema))
-            t = mstate["t"]
-            if obs_trace:
-                t = OBS.record(t, OBS.PH_APPLY, obs_round, gnorm)
-            mstate = {"m": m, "t": t}
+                if global_diag is not None:
+                    diag = dict(global_diag)
+                else:
+                    # scope == "block": selection is per-block; report the
+                    # mean over block plans (the per-block degradation is
+                    # the point)
+                    diag = {kk: jnp.mean(jnp.stack(
+                        [d[kk] for d in block_diags]), axis=0)
+                        for kk in block_diags[0]}
+                # captured mass over the rows the attack actually holds
+                # (f_eff)
+                diag["byz_mass"] = jnp.sum(diag["selection"][:f_eff])
+                diag["honest_dev"] = honest_dev_finalize(dev_sq, ref_sq)
+                if codec_obj is not None:
+                    diag["wire_bytes_per_worker"] = jnp.asarray(
+                        wire_total / rcfg.n_workers, jnp.float32)
+                if hier is not None and codec_obj is not None:
+                    diag["leader_wire_bytes"] = jnp.asarray(
+                        leader_total, jnp.float32)
+                metrics["telemetry"] = diag
+            if obs_live:
+                m = mstate["m"]
+                m = OBS.inc(m, "rounds")
+                m = OBS.set_gauge(m, "loss", metrics["loss"])
+                m = OBS.set_gauge(m, "agg_grad_norm", gnorm)
+                m = OBS.observe(m, "agg_grad_norm", gnorm)
+                if telemetry:
+                    m = OBS.set_gauge(m, "byz_mass", diag["byz_mass"])
+                    m = OBS.set_gauge(m, "suspicion", OBS.update_suspicion(
+                        m.gauges["suspicion"], diag["selection"],
+                        obs.suspicion_ema))
+                t = mstate["t"]
+                if obs_trace:
+                    t = OBS.record(t, OBS.PH_APPLY, obs_round, gnorm)
+                mstate = {"m": m, "t": t}
         return (new_params,
                 dataclasses.replace(state, opt=new_opt, mstate=mstate),
                 metrics)

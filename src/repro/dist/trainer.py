@@ -13,6 +13,13 @@ One train step (DESIGN.md §3):
    coordinate phase);
 5. one optimizer update from the aggregated gradient.
 
+Each phase traces under its ``repro.obs`` scope, so a device profile
+attributes every operation: ``robust.workers`` (1; forward ops under
+``jvp(robust.workers)``, backward under ``transpose(jvp(...))``),
+``robust.attack`` (2, the codec's wire included), ``robust.stats``,
+``robust.plan`` and ``robust.apply`` (4), ``robust.update`` (5, with the
+step's metrics).  The transforms of step 3 carry no scope of their own.
+
 The returned step has signature ``(params, state, batch, key) ->
 (params, state, metrics)`` where ``state`` is the named
 :class:`TrainerState` pytree (optimizer + transform + adaptive-attack +
@@ -409,8 +416,9 @@ def make_train_step(cfg: ArchConfig, rcfg: RobustConfig, opt: Optimizer,
         opt_state, tstates = state.opt, state.tstates
         astate, cres = state.astate, state.cres
         mstate = state.mstate
-        losses, grads = jax.vmap(
-            lambda wb: jax.value_and_grad(worker_loss)(params, wb))(batch)
+        with OBS.scope("workers"):
+            losses, grads = jax.vmap(
+                lambda wb: jax.value_and_grad(worker_loss)(params, wb))(batch)
         if obs_live and mstate is None:
             # trace-time seed: the worker count is static here, and a jit
             # caller retraces once when None becomes a live carry.  Scans
@@ -422,21 +430,22 @@ def make_train_step(cfg: ArchConfig, rcfg: RobustConfig, opt: Optimizer,
             atk = functools.partial(adaptive.propose, state=astate)
         else:
             atk = attack
-        if not wire:
-            # gradient-space adversary: proposes rows before encoding (it
-            # controls its wire messages, so it encodes like anyone else)
-            grads = inject_byzantine(grads, f_eff, atk, key)
-        enc = None
-        if codec_obj is not None:
-            # distinct fold for quantization randomness: attack leaves use
-            # fold_in(key, leaf_index), transforms 2^31-1 (below)
-            ekey = jax.random.fold_in(key, 2 ** 31 - 2)
-            enc, cres = codec_obj.encode(grads, key=ekey, residual=cres)
-            if wire:
-                enc = inject_wire(enc, f_eff, attack, key)
-            # the aggregator-side view: everything downstream (transforms,
-            # apply, honest_dev) sees what survived the wire
-            grads = codec_obj.decode(enc)
+        with OBS.scope("attack"):
+            if not wire:
+                # gradient-space adversary: proposes rows before encoding (it
+                # controls its wire messages, so it encodes like anyone else)
+                grads = inject_byzantine(grads, f_eff, atk, key)
+            enc = None
+            if codec_obj is not None:
+                # distinct fold for quantization randomness: attack leaves use
+                # fold_in(key, leaf_index), transforms 2^31-1 (below)
+                ekey = jax.random.fold_in(key, 2 ** 31 - 2)
+                enc, cres = codec_obj.encode(grads, key=ekey, residual=cres)
+                if wire:
+                    enc = inject_wire(enc, f_eff, attack, key)
+                # the aggregator-side view: everything downstream (transforms,
+                # apply, honest_dev) sees what survived the wire
+                grads = codec_obj.decode(enc)
         if grad_specs is not None and shard_map_mesh is not None:
             from jax.sharding import NamedSharding
             grads = jax.lax.with_sharding_constraint(
@@ -477,46 +486,48 @@ def make_train_step(cfg: ArchConfig, rcfg: RobustConfig, opt: Optimizer,
                     jnp.max(plan.selection_weights()))}
             agg = backend.apply(plan, grads)
         if adaptive is not None:
-            astate = adaptive.update(astate, plan.selection_weights())
-        lr = lr_fn(opt_state.step)
-        new_params, new_opt = opt.update(agg, opt_state, params, lr)
-        gnorm = jnp.sqrt(sum(
-            jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree.leaves(agg)))
-        metrics = {
-            "loss": jnp.mean(losses),
-            "loss_per_worker": losses,
-            "lr": jnp.asarray(lr, jnp.float32),
-            "agg_grad_norm": gnorm,
-        }
-        if telemetry:
-            diag = plan.diagnostics(hinfo["inner_stats"]) \
-                if hier is not None else plan.diagnostics(stats)
-            # count captured mass over the rows the attack actually holds
-            # this phase (f_eff), not the rule's contract f
-            diag["byz_mass"] = jnp.sum(diag["selection"][:f_eff])
-            diag["honest_dev"] = _honest_mean_dev(agg, grads, f_eff)
-            if enc is not None:
-                diag["wire_bytes_per_worker"] = jnp.asarray(
-                    enc.bytes_per_worker, jnp.float32)
-            if hier is not None and codec_obj is not None:
-                diag["leader_wire_bytes"] = jnp.asarray(
-                    hinfo["leader_wire_bytes"], jnp.float32)
-            metrics["telemetry"] = diag
-        if obs_live:
-            m = mstate["m"]
-            m = OBS.inc(m, "rounds")
-            m = OBS.set_gauge(m, "loss", metrics["loss"])
-            m = OBS.set_gauge(m, "agg_grad_norm", gnorm)
-            m = OBS.observe(m, "agg_grad_norm", gnorm)
+            with OBS.scope("attack"):
+                astate = adaptive.update(astate, plan.selection_weights())
+        with OBS.scope("update"):
+            lr = lr_fn(opt_state.step)
+            new_params, new_opt = opt.update(agg, opt_state, params, lr)
+            gnorm = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                                 for g in jax.tree.leaves(agg)))
+            metrics = {
+                "loss": jnp.mean(losses),
+                "loss_per_worker": losses,
+                "lr": jnp.asarray(lr, jnp.float32),
+                "agg_grad_norm": gnorm,
+            }
             if telemetry:
-                m = OBS.set_gauge(m, "byz_mass", diag["byz_mass"])
-                m = OBS.set_gauge(m, "suspicion", OBS.update_suspicion(
-                    m.gauges["suspicion"], diag["selection"],
-                    obs.suspicion_ema))
-            t = mstate["t"]
-            if obs_trace:
-                t = OBS.record(t, OBS.PH_APPLY, obs_round, gnorm)
-            mstate = {"m": m, "t": t}
+                diag = plan.diagnostics(hinfo["inner_stats"]) \
+                    if hier is not None else plan.diagnostics(stats)
+                # count captured mass over the rows the attack actually holds
+                # this phase (f_eff), not the rule's contract f
+                diag["byz_mass"] = jnp.sum(diag["selection"][:f_eff])
+                diag["honest_dev"] = _honest_mean_dev(agg, grads, f_eff)
+                if enc is not None:
+                    diag["wire_bytes_per_worker"] = jnp.asarray(
+                        enc.bytes_per_worker, jnp.float32)
+                if hier is not None and codec_obj is not None:
+                    diag["leader_wire_bytes"] = jnp.asarray(
+                        hinfo["leader_wire_bytes"], jnp.float32)
+                metrics["telemetry"] = diag
+            if obs_live:
+                m = mstate["m"]
+                m = OBS.inc(m, "rounds")
+                m = OBS.set_gauge(m, "loss", metrics["loss"])
+                m = OBS.set_gauge(m, "agg_grad_norm", gnorm)
+                m = OBS.observe(m, "agg_grad_norm", gnorm)
+                if telemetry:
+                    m = OBS.set_gauge(m, "byz_mass", diag["byz_mass"])
+                    m = OBS.set_gauge(m, "suspicion", OBS.update_suspicion(
+                        m.gauges["suspicion"], diag["selection"],
+                        obs.suspicion_ema))
+                t = mstate["t"]
+                if obs_trace:
+                    t = OBS.record(t, OBS.PH_APPLY, obs_round, gnorm)
+                mstate = {"m": m, "t": t}
         return (new_params,
                 TrainerState(opt=new_opt, tstates=tstates, astate=astate,
                              cres=cres, bstate=state.bstate,

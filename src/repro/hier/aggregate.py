@@ -156,9 +156,10 @@ def hier_aggregate_tree(grads: PyTree, f: int, cfg: GroupConfig, *,
                 "or aggregate without hier")
         k2 = None if key is None else \
             jax.random.fold_in(key, LEADER_ENCODE_FOLD)
-        enc2, _ = c.encode(inter, key=k2)
+        with OBS.scope("attack"):                 # the codec's wire
+            enc2, _ = c.encode(inter, key=k2)
+            inter = c.decode(enc2)
         info["leader_wire_bytes"] = enc2.wire_bytes
-        inter = c.decode(enc2)
 
     outer_name = cfg.resolve_outer_rule(budget)
     outer = api.get_aggregator(outer_name)

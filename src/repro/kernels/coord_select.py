@@ -111,5 +111,6 @@ def coord_select_pallas(g_ext: Array, g_agr: Array, beta: int, *,
         out_specs=pl.BlockSpec((1, d_tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
+        name="coord_select",
     )(g_ext, g_agr)
     return out[0, :d]

@@ -129,6 +129,7 @@ def dequant_stats_pallas(payload: Array, mult: Array, *, d_tile: int = 2048,
         out_shape=(jax.ShapeDtypeStruct((np_, np_), jnp.float32),
                    jax.ShapeDtypeStruct((1, np_), jnp.float32)),
         interpret=interpret,
+        name="dequant_stats",
     )(payload, mult.astype(jnp.float32)[None, :])
     return dists[:n, :n], norms[0, :n]
 
@@ -232,6 +233,7 @@ def dequant_stats_rect_pallas(p_loc: Array, m_loc: Array, p_full: Array,
         out_shape=(jax.ShapeDtypeStruct((lp, np_), jnp.float32),
                    jax.ShapeDtypeStruct((1, np_), jnp.float32)),
         interpret=interpret,
+        name="dequant_stats_rect",
     )(p_loc, m_loc.astype(jnp.float32)[None, :],
       p_full, m_full.astype(jnp.float32)[None, :])
     return dists[:n_loc, :n], norms[0, :n]

@@ -123,6 +123,7 @@ def _build_call(np_: int, dp: int, theta: int, beta: int, d_tile: int,
         out_specs=pl.BlockSpec((1, macro_tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
+        name="fused_select",
     )
 
 

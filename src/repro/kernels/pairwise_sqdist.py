@@ -88,6 +88,7 @@ def pairwise_sqdist_pallas(x: Array, *, d_tile: int = 2048,
         out_specs=pl.BlockSpec((np_, np_), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, np_), jnp.float32),
         interpret=interpret,
+        name="pairwise_sqdist",
     )(x)
     out = out[:n, :n]
     out = jnp.maximum(out, 0.0)
@@ -174,6 +175,7 @@ def pairwise_stats_pallas(x: Array, *, d_tile: int = 2048,
         out_shape=(jax.ShapeDtypeStruct((np_, np_), jnp.float32),
                    jax.ShapeDtypeStruct((1, np_), jnp.float32)),
         interpret=interpret,
+        name="pairwise_stats",
     )(x)
     return dists[:n, :n], norms[0, :n]
 
@@ -266,5 +268,6 @@ def pairwise_stats_rect_pallas(x_loc: Array, x_full: Array, *,
         out_shape=(jax.ShapeDtypeStruct((lp, np_), jnp.float32),
                    jax.ShapeDtypeStruct((1, np_), jnp.float32)),
         interpret=interpret,
+        name="pairwise_stats_rect",
     )(x_loc, x_full)
     return dists[:n_loc, :n], norms[0, :n]

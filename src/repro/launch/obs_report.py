@@ -15,8 +15,9 @@ Usage:
       [--validate] [--kernels]
 
 ``--validate`` exits 1 on any schema problem — CI runs it on the smoke
-snapshot; ``--trace`` additionally checks the Chrome-trace file parses
-and counts its events.
+snapshot; ``--trace`` additionally checks the Chrome-trace file parses,
+that its host spans have durations and its span-ring records none (they
+are instants: the ring holds no time), and counts its events.
 """
 from __future__ import annotations
 
@@ -60,6 +61,25 @@ def _digest(snap) -> None:
               f"round_us p50/p95/p99 = "
               f"{sv['round_us']['p50']:.0f}/{sv['round_us']['p95']:.0f}/"
               f"{sv['round_us']['p99']:.0f}")
+
+
+def _trace_problems(events) -> list:
+    """Host spans (pid 0) are complete events with a duration; ring
+    records (pid 1) are instants, whose time is their step's end."""
+    problems = []
+    for e in events:
+        ph, pid = e.get("ph"), e.get("pid")
+        if ph == "M":
+            continue
+        if pid == 0 and not (ph == "X" and e.get("dur", -1) >= 0):
+            problems.append(f"host event {e.get('name')!r} is not a span "
+                            "with a duration")
+        elif pid == 1 and (ph != "i" or "dur" in e):
+            problems.append(f"ring record {e.get('name')!r} carries a "
+                            "duration: the ring holds no time")
+        elif pid not in (0, 1):
+            problems.append(f"event {e.get('name')!r} on unknown pid {pid}")
+    return problems
 
 
 def _kernel_report(points: Tuple[Tuple[int, int], ...]) -> None:
@@ -114,11 +134,13 @@ def main(argv: Optional[Tuple[str, ...]] = None) -> int:
             if not isinstance(events, list) or not events:
                 problems.append(f"{args.trace}: no traceEvents")
             else:
-                n_dev = sum(1 for e in events if e.get("pid") == 1
-                            and e.get("ph") == "X")
+                problems += [f"{args.trace}: {p}"
+                             for p in _trace_problems(events)]
+                n_ring = sum(1 for e in events if e.get("ph") == "i")
+                n_host = sum(1 for e in events if e.get("ph") == "X")
                 print(f"[obs_report] trace: {len(events)} events "
-                      f"({n_dev} device-logical) — open at "
-                      "https://ui.perfetto.dev")
+                      f"({n_host} host spans, {n_ring} ring records) — "
+                      "open at https://ui.perfetto.dev")
         except FileNotFoundError:
             problems.append(f"{args.trace}: missing")
         except json.JSONDecodeError as e:

@@ -5,6 +5,14 @@ device(s).  On this CPU container it is used with reduced configs
 (``--reduced``) and the ~100M example (examples/byzantine_training.py); on a
 real TPU slice the same driver takes the production mesh path.
 
+Steps are dispatched ahead of the device; only a logged loss waits.  With
+``--obs`` or ``--profile-dir`` each step is instead three host spans,
+``batch``, ``dispatch`` and ``wait`` (the step is waited for before the
+next begins), inside a ``step`` span; they are written into a
+``jax.profiler`` trace as ``repro:<name>``.  ``--profile-dir DIR`` traces
+the last few steps into DIR: the device's operations there carry the
+step's ``robust.*`` scopes and the kernels' names.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --reduced \\
       --steps 100 --workers 12 --f 2 --gar multi_bulyan --attack sign_flip
@@ -12,6 +20,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import statistics
 import time
 
 import jax
@@ -28,6 +37,10 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro import models as MD
 from repro import obs as OBS
 from repro.optim import make_optimizer, warmup_cosine
+
+#: how many of the last steps ``--profile-dir`` traces (never step 0,
+#: which compiles)
+PROFILE_STEPS = 3
 
 
 def worker_batch(cfg, batch, key, step: int, n_workers: int):
@@ -98,6 +111,9 @@ def main(argv=None) -> int:
     ap.add_argument("--obs-trace", default="obs_trace.json",
                     help="Chrome-trace output path (with --obs); open at "
                          "https://ui.perfetto.dev")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help=f"trace the last {PROFILE_STEPS} steps (never "
+                         "step 0) with jax.profiler into DIR")
     ap.add_argument("--optimizer", default="sgd")
     ap.add_argument("--lr", type=float, default=0.05)
     ap.add_argument("--seed", type=int, default=0)
@@ -108,6 +124,9 @@ def main(argv=None) -> int:
         args.reduced = True
         args.steps = min(args.steps, 3)
         args.log_every = 1
+    if args.profile_dir and args.steps < 2:
+        raise SystemExit("--profile-dir traces warm steps: it needs "
+                         "--steps >= 2")
     enable_compile_cache()
 
     cfg = get_config(args.arch)
@@ -195,33 +214,71 @@ def main(argv=None) -> int:
         # start them there, so that every step sees one placement and the
         # step compiles once
         params, state = replicate_on_mesh((params, state), mesh)
-    tracer = OBS.SpanTracer() if args.obs else None
+    tracer = OBS.SpanTracer() if args.obs or args.profile_dir else None
+    profiled = range(max(1, args.steps - PROFILE_STEPS), args.steps) \
+        if args.profile_dir else range(0)
 
     global_batch = args.workers * args.per_worker_batch
     data = lm_batches(cfg.vocab_size, global_batch, args.seq, seed=args.seed)
     t0 = time.time()
     loss = float("nan")
+    tracing = False
     with CompileCounter() as compiles:
-        for i in range(args.steps):
-            wb = worker_batch(cfg, next(data), key, i, args.workers)
-            if tracer is not None:
-                with tracer.span("step", round=i):
+        try:
+            for i in range(args.steps):
+                if i in profiled and not tracing:
+                    jax.profiler.start_trace(args.profile_dir)
+                    tracing = True
+                if tracer is None:
+                    wb = worker_batch(cfg, next(data), key, i, args.workers)
                     params, state, metrics = step_fn(
                         params, state, wb, jax.random.fold_in(key, i))
-                    jax.block_until_ready(metrics["loss"])
-            else:
-                params, state, metrics = step_fn(params, state, wb,
-                                                 jax.random.fold_in(key, i))
-            if i == 0:
-                first_step_compiles = compiles.count
-            if i % args.log_every == 0 or i == args.steps - 1:
-                loss = float(metrics["loss"])
-                print(f"[train] step {i:5d} loss {loss:.4f} "
-                      f"lr {float(metrics['lr']):.2e} "
-                      f"({(time.time()-t0)/(i+1):.2f}s/step)", flush=True)
+                else:
+                    with tracer.span("step", round=i):
+                        with tracer.span("batch"):
+                            wb = worker_batch(cfg, next(data), key, i,
+                                              args.workers)
+                        with tracer.span("dispatch"):
+                            params, state, metrics = step_fn(
+                                params, state, wb, jax.random.fold_in(key, i))
+                        with tracer.span("wait"):
+                            jax.block_until_ready((params, state, metrics))
+                if tracing and i == profiled[-1]:
+                    t_stop = time.perf_counter()
+                    jax.profiler.stop_trace()
+                    write_s = time.perf_counter() - t_stop
+                    tracing = False
+                if i == 0:
+                    first_step_compiles = compiles.count
+                    jax.block_until_ready((params, state))
+                    t_warm = time.perf_counter()
+                if i % args.log_every == 0 or i == args.steps - 1:
+                    loss = float(metrics["loss"])
+                    print(f"[train] step {i:5d} loss {loss:.4f} "
+                          f"lr {float(metrics['lr']):.2e} "
+                          f"({(time.time()-t0)/(i+1):.2f}s/step)",
+                          flush=True)
+        finally:
+            if tracing:
+                jax.profiler.stop_trace()
     if args.steps > 1:
+        jax.block_until_ready((params, state))
+        warm_s = (time.perf_counter() - t_warm) / (args.steps - 1)
         print(f"[train] compiles after step 0: "
               f"{compiles.count - first_step_compiles}")
+        print(f"[train] warm steps: {warm_s:.6f} s/step over "
+              f"{args.steps - 1} steps (wall clock)")
+    if tracer is not None and args.steps > 1:
+        steps = {s["args"]["round"]: s["dur_us"] * 1e-6
+                 for s in tracer.spans if s["name"] == "step"}
+        warm = [t for i, t in steps.items() if i >= 1]
+        print(f"[train] warm step time: median "
+              f"{statistics.median(warm):.6f} s over {len(warm)} steps")
+    if profiled:
+        traced = statistics.median(steps[i] for i in profiled)
+        print(f"[train] profile: steps {profiled[0]}..{profiled[-1]} "
+              f"traced -> {args.profile_dir}; median step {traced:.6f} s; "
+              f"written in {write_s:.3f} s")
     if args.ckpt_dir:
         path = save(args.ckpt_dir, args.steps, {"params": params})
         print(f"[train] checkpoint -> {path}")

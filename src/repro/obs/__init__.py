@@ -4,8 +4,10 @@ Four pieces, one contract:
 
 * :mod:`repro.obs.metrics` — device-resident registry (counters /
   gauges / histograms) whose record ops are pure ``jnp`` updates;
-* :mod:`repro.obs.trace`   — stats→plan→apply→select_plan span ring in
-  the scan carry, Chrome-trace/Perfetto export at drain;
+* :mod:`repro.obs.trace`   — the ``robust.*`` named scopes the compiled
+  step carries into a device profile, the stats→plan→apply→select_plan
+  span ring in the scan carry, host spans on the profiler's clock, and
+  the Chrome-trace/Perfetto export at drain;
 * :mod:`repro.obs.profile` — kernel launch-config records paired with
   the ``analysis/vmem`` prediction;
 * :mod:`repro.obs.export`  — the host-side drain: ``obs.v1`` snapshots,
@@ -31,8 +33,9 @@ from repro.obs.metrics import (GRAD_NORM_EDGES, MetricsSpec, MetricsState,
                                set_gauge, train_spec, update_ema,
                                update_suspicion)
 from repro.obs.trace import (PH_APPLY, PH_PLAN, PH_SELECT_PLAN, PH_STATS,
-                             PHASES, SpanTracer, TraceState, drain,
-                             export_chrome_trace, init_trace, record)
+                             PHASES, SCOPE_PREFIX, SCOPES, SpanTracer,
+                             TraceState, drain, export_chrome_trace,
+                             init_trace, record, scope)
 from repro.obs.profile import (KernelProfiler, KernelRecord, measure_vmem,
                                profile_points, record_kernel)
 from repro.obs.export import (SCHEMA, metrics_to_json, percentiles,
@@ -42,12 +45,13 @@ from repro.obs.export import (SCHEMA, metrics_to_json, percentiles,
 __all__ = [
     "GRAD_NORM_EDGES", "KernelProfiler", "KernelRecord", "MetricsSpec",
     "MetricsState", "ObsConfig", "PHASES", "PH_APPLY", "PH_PLAN",
-    "PH_SELECT_PLAN", "PH_STATS", "SCHEMA", "SpanTracer", "TraceState",
+    "PH_SELECT_PLAN", "PH_STATS", "SCHEMA", "SCOPES", "SCOPE_PREFIX",
+    "SpanTracer", "TraceState",
     "drain", "ema_gauge", "export_chrome_trace", "inc", "init_metrics",
     "init_obs_state", "init_serve_obs", "init_suspicion", "init_trace",
     "init_train_obs", "measure_vmem", "metrics_to_json", "obs_on",
     "observe", "percentiles", "phase_summary", "profile_points", "record",
-    "record_kernel",
+    "record_kernel", "scope",
     "serve_metrics", "serve_spec", "set_gauge", "snapshot", "train_spec",
     "update_ema", "update_suspicion", "validate_snapshot",
     "write_snapshot",

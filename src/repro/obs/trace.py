@@ -1,25 +1,33 @@
-"""Span tracing of the stats→plan→apply→select_plan pipeline.
+"""Span tracing of the robust step: named scopes, a span ring, host spans.
 
-XLA has no in-graph wall clock, so device-side "timestamps" here are
-*logical*: each record is (seq, round, phase, payload), written into a
-fixed-capacity ring buffer that rides in the scan carry as a registered
-pytree (:class:`TraceState`).  ``seq`` is the monotone record counter —
-it orders records across ring wraparound — ``round`` is the optimizer
-step the span belongs to, ``phase`` indexes :data:`PHASES`, and
-``payload`` is one phase-specific scalar (selection mass, grad norm,
-plan_reused flag, ...).
+Three records, one phase vocabulary:
 
-Wall-clock time is attached **host-side**, at drain: the launch layer
-wraps its jitted step calls in a :class:`SpanTracer` (ordinary
-``perf_counter`` spans around device dispatch), and
-:func:`export_chrome_trace` lays the drained logical records out against
-those host anchors.  This is the same honest framing the serving loadgen
-uses (it never sleeps): we report the device pipeline's *structure* from
-in-graph records and its *duration* from host timing, and never pretend
-an in-graph number is a nanosecond.
+* **Scopes in the compiled program.**  :func:`scope` opens a
+  ``jax.named_scope("robust.<name>")`` for one of :data:`SCOPES`.  XLA
+  carries the name into every instruction's ``op_name`` metadata (fusions
+  take their root op's), so a device profile attributes each operation to
+  the workers' forward/backward (``jvp(robust.workers)`` and
+  ``transpose(jvp(robust.workers))``), the byzantine rows and the wire,
+  stats, plan, apply, or the update.  A named scope adds no jaxpr
+  equation: it costs nothing at run time and needs no switch.
+* **The span ring.**  XLA has no in-graph wall clock, so device-side
+  records are *logical*: each is (seq, round, phase, payload), written
+  into a fixed-capacity ring buffer that rides in the scan carry as a
+  registered pytree (:class:`TraceState`).  ``seq`` is the monotone record
+  counter — it orders records across ring wraparound — ``round`` is the
+  optimizer step the span belongs to, ``phase`` indexes :data:`PHASES`,
+  and ``payload`` is one phase-specific scalar (selection mass, grad
+  norm, plan_reused flag, ...).
+* **Host spans.**  :class:`SpanTracer` times what the host does around
+  the jitted step and writes the same spans into ``jax.profiler``'s trace
+  (``repro:<name>``), on the profiler's clock, so a device profile shows
+  them beside the device's operations.
 
-The exported JSON is the Chrome trace-event format — load it at
-https://ui.perfetto.dev (or chrome://tracing) directly.
+:func:`export_chrome_trace` writes the host spans and the drained ring
+records as one Chrome trace-event JSON (https://ui.perfetto.dev or
+chrome://tracing): ring records are instant events at the end of their
+step's host span, since the ring has no duration to report.  Device
+durations come from a ``jax.profiler`` trace, read by the scopes above.
 """
 from __future__ import annotations
 
@@ -41,6 +49,26 @@ Array = jax.Array
 #: first three.
 PHASES = ("stats", "plan", "apply", "select_plan")
 PH_STATS, PH_PLAN, PH_APPLY, PH_SELECT_PLAN = range(len(PHASES))
+
+#: Named scopes of the robust step, in program order: the n workers'
+#: forward and backward, the byzantine rows and the codec's wire, the
+#: three aggregation phases (as in :data:`PHASES`), the optimizer update
+#: with the step's metrics.
+SCOPES = ("workers", "attack", "stats", "plan", "apply", "update")
+SCOPE_PREFIX = "robust."
+
+
+def scope(name: str):
+    """``jax.named_scope("robust.<name>")`` for one of :data:`SCOPES`.
+
+    Open a fresh one around the code that traces (``with scope(...)``):
+    one object shared by concurrent traces would mix their name stacks.
+    A phase is never opened inside itself: the name would appear twice in
+    the ``op_name``.
+    """
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; known: {SCOPES}")
+    return jax.named_scope(SCOPE_PREFIX + name)
 
 _COLS = 4  # (seq, round, phase, payload)
 
@@ -123,13 +151,20 @@ def drain(trace: Optional[TraceState]) -> List[Dict[str, Any]]:
 class SpanTracer:
     """Host-side wall-clock spans (``perf_counter``, microseconds).
 
-    The launch layer brackets each jitted step call::
+    The launch layer brackets what the host does around each jitted step::
 
         tracer = SpanTracer()
         with tracer.span("step", round=i):
-            loss = step(params, state, ...)  # block_until_ready inside
+            with tracer.span("dispatch"):
+                out = step(params, state, ...)
+            with tracer.span("wait"):
+                jax.block_until_ready(out)
 
-    These anchor the logical device records in the exported trace.
+    Each span is also written into ``jax.profiler``'s trace when one is
+    being taken: ``repro:<name>`` (a ``TraceAnnotation``), and a ``step``
+    span with a ``round`` as a ``StepTraceAnnotation``, so a device
+    profile lines the host's work up with the device's.  The recorded
+    spans anchor the ring records in :func:`export_chrome_trace`.
     """
 
     def __init__(self):
@@ -138,9 +173,16 @@ class SpanTracer:
 
     @contextmanager
     def span(self, name: str, **args):
+        rnd = args.get("round")
+        if name == "step" and rnd is not None:
+            mark = jax.profiler.StepTraceAnnotation("repro:step",
+                                                    step_num=int(rnd))
+        else:
+            mark = jax.profiler.TraceAnnotation("repro:" + name)
         start = time.perf_counter()
         try:
-            yield
+            with mark:
+                yield
         finally:
             end = time.perf_counter()
             self.spans.append({
@@ -164,19 +206,20 @@ def export_chrome_trace(path: str, *,
     """Write a Chrome-trace/Perfetto JSON file; returns the event count.
 
     Host spans become pid 0 / tid 0 duration events at their measured
-    wall-clock offsets.  Logical device records become pid 1 duration
-    events on one track per phase: each round is laid out inside its
-    host ``step`` span when one with a matching ``round`` arg exists
-    (phases split the span evenly, in pipeline order), else on a uniform
-    1 ms/round grid.  The layout is reconstruction, not measurement —
-    ``args.logical`` is set on every device event to say so.
+    wall-clock offsets.  Ring records become pid 1 instant events, one
+    track per phase, at the end of the host ``step`` span whose ``round``
+    arg matches theirs (the step has finished its records by then).  The
+    ring holds no time, so no duration is reported for it; records whose
+    round has no such span are left out and counted in
+    ``otherData.unanchored_records``.
     """
     events: List[Dict[str, Any]] = [
         {"name": "process_name", "ph": "M", "pid": 0,
          "args": {"name": "host (wall clock)"}},
         {"name": "process_name", "ph": "M", "pid": 1,
-         "args": {"name": "device pipeline (logical, host-anchored)"}},
+         "args": {"name": "span ring (records at their step's end)"}},
     ]
+    ends = {}
     for s in host_spans:
         events.append({
             "name": s["name"], "ph": "X", "pid": 0, "tid": 0,
@@ -184,31 +227,23 @@ def export_chrome_trace(path: str, *,
             "dur": round(float(s["dur_us"]), 3),
             "cat": "host", "args": dict(s.get("args", {})),
         })
-
-    anchors = {}
-    for s in host_spans:
         rnd = s.get("args", {}).get("round")
-        if rnd is not None:
-            anchors[int(rnd)] = (float(s["ts_us"]), float(s["dur_us"]))
+        if s["name"] == "step" and rnd is not None:
+            ends[int(rnd)] = float(s["ts_us"]) + float(s["dur_us"])
 
-    by_round: Dict[int, List[Dict[str, Any]]] = {}
-    for r in device_records:
-        by_round.setdefault(int(r["round"]), []).append(r)
-    for rnd, recs in sorted(by_round.items()):
-        recs = sorted(recs, key=lambda r: r["seq"])
-        ts0, dur = anchors.get(rnd, (rnd * 1000.0, 1000.0))
-        slot = dur / max(len(recs), 1)
-        for k, r in enumerate(recs):
-            events.append({
-                "name": r["phase"], "ph": "X", "pid": 1,
-                "tid": PHASES.index(r["phase"]),
-                "ts": round(ts0 + k * slot, 3),
-                "dur": round(slot, 3),
-                "cat": "device-logical",
-                "args": {"seq": r["seq"], "round": r["round"],
-                         "payload": r["payload"], "logical": True,
-                         "anchored": rnd in anchors},
-            })
+    unanchored = 0
+    for r in sorted(device_records, key=lambda r: r["seq"]):
+        ts = ends.get(int(r["round"]))
+        if ts is None:
+            unanchored += 1
+            continue
+        events.append({
+            "name": r["phase"], "ph": "i", "s": "t", "pid": 1,
+            "tid": PHASES.index(r["phase"]), "ts": round(ts, 3),
+            "cat": "ring",
+            "args": {"seq": r["seq"], "round": r["round"],
+                     "payload": r["payload"]},
+        })
     for tid, phase in enumerate(PHASES):
         events.append({"name": "thread_name", "ph": "M", "pid": 1,
                        "tid": tid, "args": {"name": phase}})
@@ -218,9 +253,10 @@ def export_chrome_trace(path: str, *,
         "displayTimeUnit": "ms",
         "otherData": {
             "exporter": "repro.obs.trace",
-            "note": ("device events are logical ring records laid out "
-                     "against host wall-clock anchors; XLA has no "
-                     "in-graph clock"),
+            "note": ("ring records are instants at the end of their "
+                     "step's host span; device durations are in a "
+                     "jax.profiler trace, under the robust.* scopes"),
+            "unanchored_records": unanchored,
             **(meta or {}),
         },
     }

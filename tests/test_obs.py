@@ -213,3 +213,65 @@ def test_kernel_profiler_counts_leaves_sent_to_xla(monkeypatch):
     got = sorted((r.kernel, r.d) for r in prof.records)
     assert got == [("fused_select", 200), ("pairwise_stats", 200),
                    ("pairwise_stats", 300), ("xla:fused_select", 300)]
+
+
+# --------------------------------------------------------- host spans, export
+def test_export_puts_ring_records_at_their_step_end(tmp_path):
+    """Ring records carry no time: the export makes them instants at the
+    end of their round's host ``step`` span, and leaves out (and counts)
+    records of rounds with no span."""
+    from repro.launch.obs_report import _trace_problems
+    spans = [{"name": "step", "ts_us": 10.0, "dur_us": 5.0,
+              "args": {"round": 0}},
+             {"name": "dispatch", "ts_us": 11.0, "dur_us": 1.0, "args": {}},
+             {"name": "step", "ts_us": 20.0, "dur_us": 4.0,
+              "args": {"round": 1}}]
+    recs = [{"seq": s, "round": r, "phase": p, "payload": 0.5}
+            for s, (r, p) in enumerate([(0, "stats"), (0, "apply"),
+                                        (1, "plan"), (2, "stats")])]
+    path = str(tmp_path / "t.json")
+    n = OBS.export_chrome_trace(path, device_records=recs, host_spans=spans)
+    with open(path) as fh:
+        doc = json.load(fh)
+    events = doc["traceEvents"]
+    assert n == len(events)
+    ring = [e for e in events if e["pid"] == 1 and e["ph"] != "M"]
+    assert [(e["name"], e["ph"], e["ts"]) for e in ring] == [
+        ("stats", "i", 15.0), ("apply", "i", 15.0), ("plan", "i", 24.0)]
+    assert all("dur" not in e for e in ring)
+    assert doc["otherData"]["unanchored_records"] == 1
+    host = [e for e in events if e["pid"] == 0 and e["ph"] == "X"]
+    assert [(e["name"], e["ts"], e["dur"]) for e in host] == [
+        ("step", 10.0, 5.0), ("dispatch", 11.0, 1.0), ("step", 20.0, 4.0)]
+    assert _trace_problems(events) == []
+    # an invented device duration is what --validate refuses
+    bad = dict(ring[0], ph="X", dur=2.5)
+    assert _trace_problems(events + [bad])
+
+
+def test_span_tracer_writes_into_the_profiler_trace(tmp_path):
+    """Host spans land in a ``jax.profiler`` trace as ``repro:<name>``, on
+    the profiler's clock, a step span as the step it is."""
+    from jax.profiler import ProfileData
+    tracer = OBS.SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(2):
+            with tracer.span("step", round=i):
+                with tracer.span("dispatch"):
+                    x = jnp.ones(8) * i
+                with tracer.span("wait"):
+                    jax.block_until_ready(x)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+              for f in fs if f.endswith(".xplane.pb")]
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro:"):
+                    seen[e.name] = seen.get(e.name, 0) + 1
+    assert seen == {"repro:step": 2, "repro:dispatch": 2, "repro:wait": 2}
+    assert [s["name"] for s in tracer.spans] == [
+        "dispatch", "wait", "step", "dispatch", "wait", "step"]

@@ -136,3 +136,32 @@ def test_train_cli_mesh_step_compiles_once(tmp_path):
     assert out.returncode == 0, out.stderr[-4000:]
     assert "shape={'data': 2, 'model': 2}" in out.stdout, out.stdout
     assert "[train] compiles after step 0: 0" in out.stdout, out.stdout
+
+
+def test_train_cli_profiles_the_last_steps(tmp_path):
+    """``launch/train.py --profile-dir`` traces the last steps (never step
+    0, which compiles) with ``jax.profiler``; the trace holds the host's
+    ``batch`` / ``dispatch`` / ``wait`` spans of each traced step."""
+    from jax.profiler import ProfileData
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_ENABLE_COMPILATION_CACHE="false")
+    prof = tmp_path / "prof"
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--smoke",
+         "--workers", "7", "--f", "1", "--profile-dir", str(prof)],
+        env=env, cwd=root, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert f"[train] profile: steps 1..2 traced -> {prof}" in out.stdout
+    [path] = [os.path.join(d, f) for d, _, fs in os.walk(prof)
+              for f in fs if f.endswith(".xplane.pb")]
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro:"):
+                    seen[e.name] = seen.get(e.name, 0) + 1
+    assert seen == {f"repro:{n}": 2
+                    for n in ("step", "batch", "dispatch", "wait")}

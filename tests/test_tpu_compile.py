@@ -91,6 +91,40 @@ def test_coord_select_compiles(one_chip, theta, beta):
     assert "tpu_custom_call" in text
 
 
+#: each kernel's entry point at a small width, with the name its
+#: ``pallas_call`` carries into the compiled program
+_KERNELS = {
+    "pairwise_sqdist": lambda x: ops.pairwise_sqdist(x, interpret=False),
+    "pairwise_stats": lambda x: ops.pairwise_stats(x, interpret=False),
+    "pairwise_stats_rect": lambda x: ops.pairwise_stats_rect(
+        x[:4], x, interpret=False),
+    "dequant_stats": lambda x: ops.dequant_stats(
+        x.astype(jnp.int8), jnp.ones(x.shape[0]), interpret=False),
+    "dequant_stats_rect": lambda x: ops.dequant_stats_rect(
+        x[:4].astype(jnp.int8), jnp.ones(4), x.astype(jnp.int8),
+        jnp.ones(x.shape[0]), interpret=False),
+    "fused_select": lambda x: ops.fused_select(
+        x, jnp.ones((5, 11)), jnp.ones((5, 11)), 1, interpret=False),
+    "coord_select": lambda x: ops.coord_select(x[:5], x[:5], 1,
+                                               interpret=False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_KERNELS))
+def test_kernel_carries_its_name(one_chip, name):
+    """The chip's HLO names each kernel's custom call after the kernel
+    (``fused_select.3``) and keeps the name in its ``op_name``: that is
+    what a device profile and the benchmark's reduction read."""
+    import re
+    x = _spec((11, 8192), jnp.float32, one_chip)
+    calls = [line for line in _compiled_text(_KERNELS[name], x).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT\s+)?%{name}(\.\d+)? = ", line), line
+        assert f"/{name}/pallas_call" in line
+
+
 def test_sharded_stats_compile_to_row_blocks(topo, monkeypatch):
     """The mesh-native stats on a described 2x2 (data, model) mesh: each
     device's rectangular kernel contracts only its row block of the
