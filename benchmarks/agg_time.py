@@ -45,13 +45,11 @@ RULES = ("median", "multi_krum", "multi_bulyan")
 # a reduced (n, d) product — n ∈ {11, 15} × d ∈ {4096, 1e5, 1e6}:
 # interpret-mode Pallas costs hundreds of ms per call at d=1e6, so the
 # full Fig-2 grid would dwarf the rule rows.  The d=4096 cell anchors the
-# small-d end of the dispatch table; the deep cells are the monotonicity
-# evidence (us_per_call/d non-increasing — validate_bench gates on it).
+# small-d end; the deep cells are the monotonicity evidence (us_per_call/d
+# non-increasing — validate_bench gates on it).
 PATHS = (
     ("multi_bulyan[xla]", dict(use_pallas=False, fused=False)),
     ("multi_bulyan[pallas]", dict(use_pallas=True, fused=False)),
-    # "force" pins the fused kernel regardless of the dispatch table —
-    # these rows ARE the crossover measurement kernels.dispatch reads
     ("multi_bulyan[fused]", dict(use_pallas=True, fused="force")),
     ("multi_bulyan[sharded]", dict(sharded=True)),
 )

@@ -15,8 +15,7 @@ sign_flip attack, per-worker batch 2, decoder length 448:
       ``launch/train.py`` builds it) with the Pallas kernels
       (``use_pallas=True``): per-step loss, step time after warm-up (ended
       by ``block_until_ready``), compiles inside the timed steps, peak
-      device memory, and the leaves and elements each kernel and the XLA
-      substrate took;
+      device memory, and the leaves and elements each kernel took;
   (c) the same steps from the same seed with ``use_pallas=False``: equal
       plans every step, losses within ``LOSS_RTOL``;
   (d) one real whisper gradient tree through ``aggregate_tree`` with every

@@ -12,8 +12,7 @@ Three passes, none of which runs device code:
   invariant, the §10 tp-reshape seam, and single-compile trace caching.
 * :mod:`repro.analysis.vmem` — static per-tile VMEM/HBM-traffic
   estimates for the Pallas kernels, cross-checked against the
-  ``autotune_d_tile`` budget and the measured BENCH_agg_time.json
-  crossover.
+  ``autotune_d_tile`` budget and the BENCH_agg_time.json traffic.
 
 ``repro.launch.analyze`` runs all three and writes the ``analysis.v1``
 report (ANALYSIS.json); ``--strict`` makes any violation fatal, which is

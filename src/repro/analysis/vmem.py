@@ -26,8 +26,7 @@ loss).  The two-level kernels read the replicated operands once per
 an order of magnitude at benchmark scale) and the grid depth at d = 1e6
 drops from ~123 steps to ~21, so the hot path stays traffic-bound:
 :func:`diagnose_traffic_linearity` checks that claim against the
-committed benchmark, and :func:`predicted_crossover` checks the residual
-overhead model against the measured dispatch table.
+committed benchmark.
 """
 from __future__ import annotations
 
@@ -35,17 +34,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional
 
-from repro.kernels import dispatch as kdispatch
 from repro.kernels import ops
-
-#: outer grid depth at which per-step overhead (dispatch + replicated-
-#: operand fetch) would again rival the byte savings.  Inherited from the
-#: single-level era's measured bracketing at n=15 — 13 steps (fused won)
-#: vs 123 steps (fused lost 3.9×), geometric midpoint ≈ 40: the per-step
-#: cost is a property of the *step*, not of how many lanes it carries, so
-#: the depth carries over while each two-level step now spans
-#: ``macro_tile`` lanes instead of ``d_tile``.
-OVERHEAD_GRID_STEPS = 40
 
 _PAYLOAD_ITEMSIZE = {"int8": 1, "bfloat16": 2}
 
@@ -209,33 +198,6 @@ def estimate(kernel: str, n: int, d: int, **kw) -> KernelEstimate:
         raise ValueError(f"unknown kernel {kernel!r}; "
                          f"known: {sorted(_ESTIMATORS)}")
     return _ESTIMATORS[kernel](n, d, **kw)
-
-
-def predicted_crossover(n: int, *, f: Optional[int] = None) -> Dict:
-    """Static fused-vs-XLA crossover numel for one n, vs the measured one.
-
-    The asymptotic macro block (d → ∞) times the overhead grid depth
-    gives the numel past which residual per-step overhead *could* rival
-    the byte savings; the measured counterpart is ``kernels/dispatch.py``'s
-    table.  Since the two-level rewrite the benchmark has no measured
-    loss point — the table is right-censored at the largest measured win
-    — so calibration is one-sided there: the model must predict the win
-    region extends at least to the measured frontier (``ratio >= 1``).
-    Against a genuinely bracketed crossover (a measured loss exists, as
-    in the single-level era) the two-sided [0.5, 2] band applies.
-    """
-    est = estimate_fused_select(n, 10 ** 9, f=f)     # asymptotic tiles
-    predicted = OVERHEAD_GRID_STEPS * est.macro_tile
-    measured = kdispatch.FUSED_MAX_NUMEL.get(
-        n, kdispatch.DEFAULT_FUSED_MAX_NUMEL)
-    _, lose = kdispatch.MEASURED_POINTS.get(n, (0, None))
-    censored = lose is None
-    ratio = predicted / measured if measured else math.inf
-    calibrated = (ratio >= 1.0) if censored else (0.5 <= ratio <= 2.0)
-    return {"n": n, "d_tile": est.d_tile, "macro_tile": est.macro_tile,
-            "grid_threshold": OVERHEAD_GRID_STEPS,
-            "predicted_numel": predicted, "measured_numel": measured,
-            "censored": censored, "ratio": ratio, "calibrated": calibrated}
 
 
 def bench_points(bench_results: dict, row: str = "multi_bulyan[fused]"

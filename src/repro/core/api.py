@@ -635,32 +635,18 @@ def _bulyan_leaf(w_ext: Array, w_agr: Array, beta: int,
     keep the parameter-dim sharding, and the coordinate phase is purely
     elementwise/axis-0 over (theta, ...).
 
-    With ``use_pallas`` and ``fused=True`` the apply phase runs in the
-    ``fused_select`` kernel (extraction einsums + coordinate phase per
-    d-tile in VMEM, no (θ, numel) HBM intermediates) — *unless* the leaf
-    sits past the measured large-d crossover where the fused kernel loses
-    to plain XLA (``kernels.dispatch.fused_wins``, read off
-    BENCH_agg_time.json), in which case the XLA substrate is taken.
-    ``fused="force"`` pins the kernel regardless (the substrate
-    benchmarks); ``fused=False`` keeps the two-step Pallas path
-    (materialised einsums + ``coord_select``) for benchmarking the fusion
-    win.
+    With ``use_pallas`` and ``fused`` (``True`` or ``"force"``, which
+    means the same) the apply phase runs in the ``fused_select`` kernel
+    at every leaf size (extraction einsums + coordinate phase per d-tile
+    in VMEM, no (θ, numel) HBM intermediates); ``fused=False`` keeps the
+    two-step Pallas path (materialised einsums + ``coord_select``) for
+    benchmarking the fusion win.
     """
     if use_pallas and fused:
-        numel = 1
-        for s in leaf.shape[1:]:
-            numel *= int(s)
-        from repro.kernels import dispatch as kdispatch
-        if fused == "force" or kdispatch.fused_wins(w_ext.shape[1], numel):
-            from repro.kernels import ops as kops
-            x = _leaf2d(leaf).astype(jnp.float32)  # (n, numel)
-            out = kops.fused_select(x, w_ext, w_agr, beta)
-            return out.reshape(leaf.shape[1:]).astype(leaf.dtype)
-        # measured-crossover fallback: past the cliff the whole Pallas
-        # stack loses (two-step loses too) — take the XLA substrate
-        from repro.obs import profile as _prof
-        _prof.record_xla("fused_select", n=w_ext.shape[1], d=numel)
-        use_pallas = False
+        from repro.kernels import ops as kops
+        x = _leaf2d(leaf).astype(jnp.float32)      # (n, numel)
+        out = kops.fused_select(x, w_ext, w_agr, beta)
+        return out.reshape(leaf.shape[1:]).astype(leaf.dtype)
 
     if use_pallas or coord_chunk:
         x = _leaf2d(leaf).astype(jnp.float32)      # (n, numel)
@@ -756,20 +742,6 @@ def _sharded_apply_leaf(plan: "AggPlan", leaf: Array, ctx: MeshContext,
         w_ext = jnp.pad(plan.w_ext, ((0, 0), (0, n_pad - n)))
         w_agr = jnp.pad(plan.w_agr, ((0, 0), (0, n_pad - n)))
 
-    # per-shard fused-vs-XLA dispatch on the static per-device leaf size
-    # (the kernel a device actually runs is (n, d_pad/M)); past the
-    # measured crossover the whole Pallas stack falls back to XLA, as in
-    # _bulyan_leaf
-    take_fused = bool(use_pallas and fused)
-    take_pallas = use_pallas
-    if take_fused and fused != "force":
-        from repro.kernels import dispatch as kdispatch
-        take_fused = kdispatch.fused_wins(n_pad, d_pad // M)
-        take_pallas = take_fused
-        if not take_fused:
-            from repro.obs import profile as _prof
-            _prof.record_xla("fused_select", n=n_pad, d=d_pad // M)
-
     def local(xl):                                     # (n_loc, d_loc)
         xfull = jax.lax.all_gather(xl, ctx.worker_axes, axis=0, tiled=True)
         xfull = dequant(xfull, mult_pad)
@@ -777,14 +749,14 @@ def _sharded_apply_leaf(plan: "AggPlan", leaf: Array, ctx: MeshContext,
             return jnp.sum(xfull, axis=0) / n
         if kind == "weighted":
             return jnp.tensordot(w, xfull, axes=(0, 0))
-        if take_fused:
+        if use_pallas and fused:
             from repro.kernels import ops as kops
             return kops.fused_select(xfull, w_ext, w_agr, plan.beta)
         g_ext = jnp.matmul(w_ext, xfull,
                            precision=jax.lax.Precision.HIGHEST)
         g_agr = jnp.matmul(w_agr, xfull,
                            precision=jax.lax.Precision.HIGHEST)
-        if take_pallas:
+        if use_pallas:
             from repro.kernels import ops as kops
             return kops.coord_select(g_ext, g_agr, plan.beta)
         return G.bulyan_coordinate_phase(g_ext, g_agr, plan.beta)
@@ -881,10 +853,9 @@ class Aggregator:
         """Plan application — shared across rules, dispatched on plan.kind.
 
         With ``use_pallas`` the bulyan kind takes the fully fused kernel
-        path (one HBM read per leaf, no (θ, d) intermediates) below the
-        measured large-d crossover and the XLA substrate above it
-        (``kernels.dispatch``); pass ``fused="force"`` to pin the kernel,
-        ``fused=False`` to benchmark the two-step Pallas path instead.
+        path (one HBM read per leaf, no (θ, d) intermediates) at every
+        leaf size; ``fused="force"`` means the same as ``True``, and
+        ``fused=False`` benchmarks the two-step Pallas path instead.
 
         An :class:`EncodedGrads` wire container is decoded first — the
         apply phase mixes values across workers, so it runs on the

@@ -4,9 +4,8 @@
 ``core.api.aggregate_tree``: per-group stats → per-group plan → per-group
 apply, then the same three phases once more over the ``(n_groups, ...)``
 group-aggregate stack.  Everything inside each level is the *existing*
-machinery — the registry rules, the fused Pallas select kernels (with the
-measured-crossover dispatch), the ``repro.comm`` codecs — composed, not
-reimplemented:
+machinery — the registry rules, the fused Pallas select kernels, the
+``repro.comm`` codecs — composed, not reimplemented:
 
 * statistics never touch an (n, n) matrix — only ceil(n/g) independent
   (≤g, ≤g) matrices plus one (n_groups, n_groups) matrix, the O(n·g)

@@ -43,9 +43,9 @@ weight einsums bit-for-bit in interpret mode (tested in
 tests/test_substrates.py): the θ-axis median picks by rank count the
 values the reference's stable sort picks, ties in the β-selection break by
 row index, and the masked mean adds the rows in the reference's order
-(``kernels/coord_select.coordinate_phase``).  The worker axis is
-zero-padded to a sublane multiple of 8 (exact: padded weight columns are
-zero).
+(``kernels/coord_select.coordinate_phase``).  The worker axis is not
+padded: each block spans all n rows, so the kernel reads the (n, d) stack
+as it is and no padded copy of it is made.
 """
 from __future__ import annotations
 
@@ -61,7 +61,7 @@ Array = jax.Array
 
 
 def _select_tile(x, we, wa, *, beta: int):
-    """The per-window pipeline: (n_pad, dt) fp32 tile + resident weights
+    """The per-window pipeline: (n, dt) fp32 tile + resident weights
     -> (dt,) aggregate.  Column-independent — see module header."""
     # extraction einsums — MXU, contraction over the worker axis.  HIGHEST:
     # ext feeds the median/selection, so it must not lose bits to bf16-pass
@@ -83,7 +83,7 @@ def _kernel(x_ref, we_ref, wa_ref, o_ref, *, beta: int, d_tile: int,
             windows: int):
     # One read of the replicated weight pair per MACRO step; the inner
     # windows all close over the loaded values.
-    we = we_ref[...]                                 # (theta, n_pad) fp32
+    we = we_ref[...]                                 # (theta, n) fp32
     wa = wa_ref[...]
 
     def window(j, carry):
@@ -101,11 +101,11 @@ def _kernel(x_ref, we_ref, wa_ref, o_ref, *, beta: int, d_tile: int,
 
 
 @functools.lru_cache(maxsize=256)
-def _build_call(np_: int, dp: int, theta: int, beta: int, d_tile: int,
+def _build_call(n: int, dp: int, theta: int, beta: int, d_tile: int,
                 macro_tile: int, interpret: bool):
     """Cached pallas_call builder keyed on the fully resolved launch config.
 
-    Building the call (closing the BlockSpecs over the padded geometry) is
+    Building the call (closing the BlockSpecs over the geometry) is
     pure Python; caching it means repeat launches at the same geometry —
     every trainer step — skip the spec construction and reuse one callable
     identity, which also keeps the surrounding jit caches warm.
@@ -114,11 +114,11 @@ def _build_call(np_: int, dp: int, theta: int, beta: int, d_tile: int,
     return pl.pallas_call(
         functools.partial(_kernel, beta=beta, d_tile=d_tile,
                           windows=windows),
-        grid=(dp // macro_tile,),
+        grid=(pl.cdiv(dp, macro_tile),),
         in_specs=[
-            pl.BlockSpec((np_, macro_tile), lambda i: (0, i)),
-            pl.BlockSpec((theta, np_), lambda i: (0, 0)),
-            pl.BlockSpec((theta, np_), lambda i: (0, 0)),
+            pl.BlockSpec((n, macro_tile), lambda i: (0, i)),
+            pl.BlockSpec((theta, n), lambda i: (0, 0)),
+            pl.BlockSpec((theta, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, macro_tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
@@ -133,9 +133,15 @@ def fused_select_pallas(x: Array, w_ext: Array, w_agr: Array, beta: int, *,
     """(n, d) stack + (θ, n) plan weights -> (d,) fp32 Bulyan aggregate.
 
     ``macro_tile`` (a multiple of ``d_tile``; default ``d_tile`` — the
-    single-level layout) sets the outer-grid block width; the lane axis is
-    padded to a ``macro_tile`` multiple.  Output is bitwise-invariant to
-    the choice (column independence — module header).
+    single-level layout) sets the outer-grid block width.  Output is
+    bitwise-invariant to the choice (column independence — module header).
+
+    Past one macro block the lane axis is padded only to a multiple of
+    128, never to one of ``macro_tile``: the last macro block may be
+    partial.  Its lanes past the stack hold whatever the block buffer
+    held, and since every stage is column-independent they reach only
+    output lanes that are never written back.  So the grid costs no copy
+    of a large stack whose d is a multiple of 128.
     """
     if x.ndim != 2:
         raise ValueError(f"x must be (n, d), got shape {x.shape}")
@@ -160,20 +166,18 @@ def fused_select_pallas(x: Array, w_ext: Array, w_agr: Array, beta: int, *,
     # a d_tile multiple, so the clamp preserves the divisibility invariant
     d_cap = ((d - 1) // d_tile + 1) * d_tile
     macro_tile = min(macro_tile, d_cap)
-    n_pad = (-n) % 8
-    d_pad = (-d) % macro_tile
-    if n_pad or d_pad:
-        x = jnp.pad(x, ((0, n_pad), (0, d_pad)))
-    if n_pad:
-        w_ext = jnp.pad(w_ext, ((0, 0), (0, n_pad)))
-        w_agr = jnp.pad(w_agr, ((0, 0), (0, n_pad)))
+    # an operand narrower than one macro block is padded to it; a wider
+    # one only to the lane width, its last block then partial
+    d_pad = (-d) % (macro_tile if d < macro_tile else 128)
+    if d_pad:
+        x = jnp.pad(x, ((0, 0), (0, d_pad)))
     # pad/cast hoisted: only cast when the dtype actually differs — a fp32
     # caller (every plan produced by core.gar) pays no per-call convert op
     if w_ext.dtype != jnp.float32:
         w_ext = w_ext.astype(jnp.float32)
     if w_agr.dtype != jnp.float32:
         w_agr = w_agr.astype(jnp.float32)
-    np_, dp = x.shape
-    call = _build_call(np_, dp, theta, beta, d_tile, macro_tile, interpret)
+    call = _build_call(n, x.shape[1], theta, beta, d_tile, macro_tile,
+                       interpret)
     out = call(x, w_ext, w_agr)
     return out[0, :d]

@@ -9,7 +9,7 @@ if "jax" not in sys.modules:                       # keep test imports inert
 
 Runs the three ``repro.analysis`` passes — the AST lint (R001–R005), the
 jaxpr contract auditors (C201–C205) under a forced 8-device host mesh,
-and the Pallas VMEM/crossover estimator — and writes the ``analysis.v1``
+and the Pallas VMEM estimator — and writes the ``analysis.v1``
 report.  No accelerator is required and no training step executes: the
 auditors only *trace*.
 
@@ -17,9 +17,9 @@ Usage:
   PYTHONPATH=src python -m repro.launch.analyze [--json ANALYSIS.json]
   PYTHONPATH=src python -m repro.launch.analyze --strict   # CI gate
 
-``--strict`` exits nonzero on any lint violation, any violated contract,
-a failed traffic-linearity diagnosis, or an uncalibrated crossover — the
-gate every kernel/sharding PR must pass.
+``--strict`` exits nonzero on any lint violation, any violated contract
+or a failed traffic-linearity diagnosis — the gate every kernel/sharding
+PR must pass.
 """
 import argparse
 import json
@@ -83,9 +83,7 @@ def run_kernels(bench_path: str) -> Dict:
         kernels[kernel] = {
             f"n={n},d={d}": vmem.estimate(kernel, n, d).to_json()
             for n, d in KERNEL_POINTS}
-    out = {"kernels": kernels,
-           "crossover": {f"n={n}": vmem.predicted_crossover(n)
-                         for n in (11, 15)}}
+    out = {"kernels": kernels}
     if os.path.isfile(bench_path):
         with open(bench_path) as fh:
             bench = json.load(fh)
@@ -112,12 +110,6 @@ def gate_problems(report: Dict) -> List[str]:
     if not traffic.get("holds"):
         problems.append("vmem traffic-linearity diagnosis does not hold: "
                         f"{traffic.get('detail')}")
-    for key, x in report["analysis"]["crossover"].items():
-        if not x["calibrated"]:
-            problems.append(
-                f"crossover {key}: predicted {x['predicted_numel']} vs "
-                f"measured {x['measured_numel']} (ratio {x['ratio']:.2f}, "
-                f"censored={x['censored']}) — model uncalibrated")
     d1e6 = report["analysis"]["kernels"]["fused_select"].get("n=15,d=1000000")
     if d1e6 and not (d1e6["over_budget"] and not d1e6["tile_over_budget"]
                      and d1e6["macro_tile"] > d1e6["d_tile"]):
@@ -164,10 +156,6 @@ def main(argv=None) -> int:
         print(f"{name}: {res['status']} — {res['detail']}")
     traffic = res_["analysis"]["traffic_linearity"]
     print(f"vmem traffic linearity: holds={traffic.get('holds')}")
-    for key, x in sorted(res_["analysis"]["crossover"].items()):
-        print(f"crossover {key}: predicted numel {x['predicted_numel']:,} "
-              f"vs measured {x['measured_numel']:,} "
-              f"(ratio {x['ratio']:.2f}, censored={x['censored']})")
     if problems:
         print(f"\n{len(problems)} problem(s):")
         for p in problems:

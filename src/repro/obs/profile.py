@@ -89,13 +89,6 @@ def record_kernel(kernel: str, *, n: int, d: int, d_tile: int,
         profiler.records.append(rec)
 
 
-def record_xla(kernel: str, *, n: int, d: int) -> None:
-    """A (n, d) leaf that ``kernels.dispatch`` sent to the XLA substrate
-    in place of ``kernel``: one record named ``xla:<kernel>``, so that a
-    profile accounts for every leaf of the phase."""
-    record_kernel(f"xla:{kernel}", n=n, d=d, d_tile=d)
-
-
 def _predict(kernel: str, *, n: int, d: int, d_tile: int, macro_tile: int,
              theta: Optional[int], dtype: Optional[str]):
     # lazy import: vmem imports kernels.ops at module load, and ops

@@ -1,4 +1,4 @@
-"""repro.hier — hierarchical aggregation: parity, budgets, dispatch, sim.
+"""repro.hier — hierarchical aggregation: parity, budgets, apply kernel, sim.
 
 The load-bearing acceptance test is *bitwise* flat parity: with g >= n the
 hierarchy degenerates to a single group and must reproduce
@@ -6,8 +6,6 @@ hierarchy degenerates to a single group and must reproduce
 on the PR-2 edge grid (n not divisible by 8, d not divisible by 128).
 """
 from __future__ import annotations
-
-import json
 
 import numpy as np
 import pytest
@@ -195,52 +193,9 @@ def test_encoded_input_and_leader_reencode():
 
 
 # ========================================================================
-# measured-crossover dispatch (kernels.dispatch)
+# the Bulyan apply's kernel under use_pallas
 # ========================================================================
-def test_fused_wins_measured_points():
-    from repro.kernels import dispatch
-    assert dispatch.fused_wins(15, 100_000)          # measured win
-    # two-level kernel: the d=1e6 cell flipped from the single-level
-    # era's 2x loss to a measured win — deep applies route to fused now
-    assert dispatch.fused_wins(15, 1_000_000)
-    assert dispatch.fused_wins(11, 1_000_000)
-    # unmeasured n inherits the win frontier (no measured loss remains)
-    assert dispatch.fused_wins(23, dispatch.DEFAULT_FUSED_MAX_NUMEL)
-    assert not dispatch.fused_wins(23, dispatch.DEFAULT_FUSED_MAX_NUMEL + 1)
-
-
-def test_load_measured_rebuilds_table(tmp_path):
-    from repro.kernels import dispatch
-    saved = dict(dispatch.MEASURED_POINTS)
-    payload = {"results": {
-        "multi_bulyan[fused]": {"n=9,d=100": 1.0, "n=9,d=10000": 9.0},
-        "multi_bulyan[xla]": {"n=9,d=100": 2.0, "n=9,d=10000": 3.0},
-    }}
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps(payload))
-    try:
-        dispatch.load_measured(str(p))
-        assert dispatch.MEASURED_POINTS == {9: (100, 10000)}
-        assert dispatch.fused_wins(9, 999)       # geomean(100,1e4) = 1000
-        assert not dispatch.fused_wins(9, 1001)
-        # all-wins payload: the censored table falls back to the frontier
-        p2 = tmp_path / "bench_wins.json"
-        p2.write_text(json.dumps({"results": {
-            "multi_bulyan[fused]": {"n=9,d=100": 1.0, "n=9,d=10000": 2.0},
-            "multi_bulyan[xla]": {"n=9,d=100": 2.0, "n=9,d=10000": 3.0},
-        }}))
-        dispatch.load_measured(str(p2))
-        assert dispatch.MEASURED_POINTS == {9: (10000, None)}
-        assert dispatch.DEFAULT_FUSED_MAX_NUMEL == 10000
-        assert dispatch.fused_wins(9, 10000)
-        assert not dispatch.fused_wins(9, 10001)
-    finally:
-        dispatch.MEASURED_POINTS = saved
-        dispatch.FUSED_MAX_NUMEL, dispatch.DEFAULT_FUSED_MAX_NUMEL = \
-            dispatch._build_table(saved)
-
-
-def test_apply_dispatch_falls_back_past_crossover(monkeypatch):
+def _spy_fused_select(monkeypatch):
     from repro.kernels import ops as kops
     calls = []
     real = kops.fused_select
@@ -250,24 +205,33 @@ def test_apply_dispatch_falls_back_past_crossover(monkeypatch):
         return real(*args, **kw)
 
     monkeypatch.setattr(kops, "fused_select", spy)
-    small = jax.random.normal(KEY, (11, 100), jnp.float32)
-    api.aggregate_tree({"w": small}, 2, name="multi_bulyan",
-                       use_pallas=True)
-    assert calls, "below the crossover the fused kernel must be used"
-    calls.clear()
-    from repro.kernels import dispatch
-    # pin a small threshold: the real refreshed table routes everything
-    # measured to fused, which would make this exercise a d > 1e6 apply
-    monkeypatch.setattr(dispatch, "FUSED_MAX_NUMEL", {})
-    monkeypatch.setattr(dispatch, "DEFAULT_FUSED_MAX_NUMEL", 4096)
-    big_d = dispatch.DEFAULT_FUSED_MAX_NUMEL + 1
-    big = jax.random.normal(KEY, (23, big_d), jnp.float32)
-    api.aggregate_tree({"w": big}, 2, name="multi_bulyan", use_pallas=True)
-    assert not calls, "past the crossover the XLA substrate must be taken"
-    # "force" pins the kernel regardless of the table
-    api.aggregate_tree({"w": big}, 2, name="multi_bulyan", use_pallas=True,
-                       fused="force")
-    assert calls
+    return calls
+
+
+@pytest.mark.parametrize("fused", [True, "force"])
+@pytest.mark.parametrize("d", [100, 1_048_577])
+def test_apply_takes_fused_select_at_every_leaf_size(monkeypatch, d, fused):
+    """Under ``use_pallas`` every leaf's apply runs ``fused_select``, past
+    1e6 coordinates as below.  Traced only (``jax.eval_shape``): the big
+    leaf never runs through the interpreted kernel."""
+    calls = _spy_fused_select(monkeypatch)
+    leaf = jax.ShapeDtypeStruct((11, d), jnp.float32)
+    out = jax.eval_shape(lambda g: api.aggregate_tree(
+        g, 2, name="multi_bulyan", use_pallas=True, fused=fused), {"w": leaf})
+    assert calls == [(11, d)]
+    assert out["w"].shape == (d,)
+
+
+@pytest.mark.parametrize("use_pallas,fused", [(False, True), (True, False)])
+def test_apply_without_fused_select(monkeypatch, use_pallas, fused):
+    """The XLA substrate (``use_pallas=False``) and the two-step Pallas
+    path (``fused=False``) keep their meaning: no ``fused_select``."""
+    calls = _spy_fused_select(monkeypatch)
+    leaf = jax.ShapeDtypeStruct((11, 1_048_577), jnp.float32)
+    jax.eval_shape(lambda g: api.aggregate_tree(
+        g, 2, name="multi_bulyan", use_pallas=use_pallas, fused=fused),
+        {"w": leaf})
+    assert calls == []
 
 
 # ========================================================================

@@ -229,6 +229,31 @@ def test_fused_apply_bitwise_vs_unfused(rule, n, f, d):
     np.testing.assert_array_equal(unfused, fused)
 
 
+@pytest.mark.parametrize("shape", [(11, 519, 384), (11, 519, 385),
+                                   (11, 37, 300)])
+def test_fused_apply_bitwise_vs_xla_on_3d_leaves(shape):
+    """A 3-D leaf whose trailing dims are not (8, 128)-aligned and whose
+    size is no multiple of the kernel's macro block (the first two end in
+    a partial block, the last fits in one): the fused apply on the (n, d)
+    reshape equals the XLA substrate's apply on the leaf, bit for bit.
+    The CPU's XLA dot rounds by the width of its operand, so the XLA side
+    runs over the kernel's lane windows (``coord_chunk`` = its d_tile)."""
+    from repro.core import api
+    from repro.kernels import ops
+    n, f = shape[0], 2
+    d = int(np.prod(shape[1:]))
+    G = _edge_stack(n, d).reshape(shape)
+    agg = api.get_aggregator("multi_bulyan")
+    plan = agg.plan(api.compute_stats({"w": G}, f, needs_dists=True))
+    d_tile, macro = ops.fused_select_tiles(16, d, plan.w_ext.shape[0])
+    assert d % macro
+    xla = np.asarray(agg.apply(plan, {"w": G}, use_pallas=False,
+                               coord_chunk=d_tile)["w"])
+    fused = np.asarray(agg.apply(plan, {"w": G}, use_pallas=True)["w"])
+    assert fused.shape == shape[1:]
+    np.testing.assert_array_equal(xla, fused)
+
+
 @pytest.mark.parametrize("n,f", EDGE_GRID)
 def test_fused_apply_degenerate_width(n, f):
     """d=1 (single coordinate): XLA lowers the unfused einsum to a gemv

@@ -195,3 +195,51 @@ def test_mesh_step_returns_replicated_params(topo, monkeypatch):
     out = jax.tree.leaves(compiled.output_shardings[:2])
     assert out and all(s.is_equivalent_to(rep, 2) for s in out)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _instructions(text: str, name: str) -> list:
+    import re
+    return [line for line in text.splitlines()
+            if re.match(rf"\s*(ROOT\s+)?%{name}(\.\d+)? = ", line)]
+
+
+def _sorts(text: str) -> list:
+    return [line for line in text.splitlines() if " sort(" in line]
+
+
+def test_apply_of_a_big_leaf_runs_fused_select(one_chip, monkeypatch):
+    """``aggregate_tree`` under ``use_pallas`` on whisper's vocabulary leaf
+    (19.9M coordinates): the apply is one ``fused_select`` call and sorts
+    nothing; the round's only sort is the plan's over the (n, n) scores."""
+    from repro.core import api
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    g = _spec((11, 51865, 384), jnp.float32, one_chip)
+    text = _compiled_text(lambda x: api.aggregate_tree(
+        {"w": x}, 2, "multi_bulyan", use_pallas=True), g)
+    assert len(_instructions(text, "fused_select")) == 1
+    sorts = _sorts(text)
+    assert not [s for s in sorts if "robust.apply" in s]
+    assert all("f32[11,11]" in s and "robust.plan" in s for s in sorts)
+
+
+def test_sharded_apply_of_a_big_leaf_runs_fused_select(topo, monkeypatch):
+    """The mesh-native apply (``_sharded_apply_leaf``) on a described 2x2
+    (data, model) mesh, each device's share of the leaf past 1e6
+    coordinates: every device runs ``fused_select`` on its (n, d/2)
+    block, and nothing under ``robust.apply`` sorts."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import api
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    ctx = api.MeshContext.for_mesh(mesh)
+    n, d = 11, 2 * 1_048_704
+    g = _spec((n, d), jnp.float32, NamedSharding(mesh, P()))
+    text = _compiled_text(lambda x: api.aggregate_tree(
+        {"w": x}, 2, "multi_bulyan", use_pallas=True, mesh_ctx=ctx), g)
+    calls = _instructions(text, "fused_select")
+    # 11 rows pad to 12 over the 2 data shards; d halves over 'model'
+    assert len(calls) == 1 and "f32[12,1048704]" in calls[0]
+    assert not [s for s in _sorts(text) if "robust.apply" in s]
